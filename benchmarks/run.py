@@ -13,6 +13,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch.cache import enable_compilation_cache
+
     from . import (
         bench_breakdown,
         bench_coldstart,
@@ -22,6 +24,7 @@ def main() -> None:
         bench_throughput,
     )
 
+    enable_compilation_cache()
     benches = [
         ("fig5_coldstart", bench_coldstart.run),
         ("table2_breakdown", bench_breakdown.run),

@@ -12,7 +12,9 @@ execution path via a seeded :class:`~repro.core.FaultInjector`; the same
 reports the typed failure taxonomy (shed / timeout / fault_recovered /
 fault_fatal), tier-health counters (repairs, retries, breaker trips) and
 the injected-fault counts next to the usual latency percentiles, so a
-chaos run reads like a bench row.
+chaos run reads like a bench row.  Without ``--chaos`` the exit code is 1
+when any replayed request failed; under a fault profile failures are the
+expected outcome and are only reported.
 """
 
 import argparse
@@ -22,6 +24,7 @@ import tempfile
 
 from repro.configs import get_config, reduced
 from repro.core import CHAOS_PROFILES, FaultInjector, TierSpec, chaos_profile
+from repro.launch.cache import enable_compilation_cache
 from repro.models import build_model
 from repro.serving import (
     AutoscaleConfig,
@@ -77,6 +80,7 @@ def main(argv=None) -> int:
                     help="cluster root (default: a fresh temp dir)")
     args = ap.parse_args(argv)
 
+    enable_compilation_cache()
     injector = None
     tiers = TierSpec(ram_bytes=1 << 30)
     if args.chaos is not None:
@@ -131,7 +135,7 @@ def main(argv=None) -> int:
             "injected": metrics.get("chaos", {}),
         }
     print(json.dumps(out, indent=2, default=str))
-    return 0
+    return 1 if rep.n_failed and args.chaos is None else 0
 
 
 if __name__ == "__main__":

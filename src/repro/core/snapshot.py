@@ -192,8 +192,9 @@ def unflatten_paths(flat: Dict[Path, np.ndarray]) -> Dict[str, Any]:
 
 
 def _array_bytes(arr: np.ndarray) -> memoryview:
-    arr = np.ascontiguousarray(arr)
-    return memoryview(arr).cast("B")
+    # a byte view, not memoryview(arr): the buffer protocol has no code for
+    # extension dtypes such as bfloat16
+    return memoryview(np.ascontiguousarray(arr).reshape(-1).view(np.uint8))
 
 
 # --------------------------------------------------------------------------

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .compat import shard_map
 
 PyTree = Any
 
@@ -64,7 +63,7 @@ def ef_compressed_mean(
 
     other = tuple(a for a in mesh.axis_names if a != axis)
     in_spec = P(axis, *([None] * (partial.ndim - 1)))
-    return shard_map(
+    return jax.shard_map(
         inner, mesh=mesh, in_specs=(in_spec, in_spec),
         out_specs=(in_spec, in_spec), check_vma=False,
     )(partial, error)

@@ -1,4 +1,4 @@
-"""Pallas TPU kernels (validated on CPU via interpret=True):
+"""Pallas TPU kernels (validated on CPU in tests via interpret=True):
 
 * flash_attention — online-softmax attention (GQA, sliding window, softcap)
 * ssd             — Mamba-2 SSD chunked scan with VMEM-carried state

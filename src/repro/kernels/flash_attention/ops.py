@@ -2,8 +2,8 @@
 
 Layout adapter: the model stack uses (b, s, heads, hd); the kernel tiles
 (b, heads, s, hd).  ``flash_attention_op`` transposes at the boundary and
-dispatches kernel vs. oracle (CPU containers run interpret=True for
-validation; real TPUs run the compiled kernel)."""
+dispatches kernel vs. oracle (CPU tests pass interpret=True; by default
+the kernel is compiled for the TPU)."""
 
 from __future__ import annotations
 
@@ -34,7 +34,7 @@ def flash_attention_op(
     softcap: float = 0.0,
     block_q: int = 512,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
     use_kernel: bool = True,
 ) -> jax.Array:
     qt = q.transpose(0, 2, 1, 3)

@@ -13,7 +13,10 @@ Two modes:
               out = base + scale · diff
 
 Layout: arrays are viewed as (n_chunks, chunk_elems).  ``sel`` maps output
-chunk i → row of ``diff`` (or -1 → base row i).
+chunk i → row of ``diff`` (or -1 → base row i).  Inside, each chunk is tiled
+as (chunk_elems // 128, 128) with the chunk axis squeezed out of the block,
+so a block's last two dims are the whole tile (the TPU's block-shape rule);
+chunk_elems must therefore be a multiple of 128.
 """
 
 from __future__ import annotations
@@ -24,6 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+LANES = 128  # last dim of a TPU vector tile
 
 
 def _kernel_replace(sel_ref, base_ref, diff_ref, out_ref):
@@ -49,6 +54,11 @@ def patch_apply(
 ) -> jax.Array:
     n, c = base.shape
     assert diff.shape[1] == c and sel.shape == (n,)
+    if c % LANES:
+        raise ValueError(
+            f"patch_apply: chunk of {c} elements is not a multiple of "
+            f"{LANES}; the TPU kernel tiles chunks as (c // {LANES}, {LANES})"
+        )
 
     if mode == "replace":
         kern = _kernel_replace
@@ -57,20 +67,23 @@ def patch_apply(
     else:
         raise ValueError(mode)
 
+    tile = (None, c // LANES, LANES)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n,),
         in_specs=[
-            pl.BlockSpec((1, c), lambda i, sel: (i, 0)),
+            pl.BlockSpec(tile, lambda i, sel: (i, 0, 0)),
             # fetch the selected diff row; clamp -1 → row 0 (discarded by the
             # in-kernel select) so the DMA address is always valid.
-            pl.BlockSpec((1, c), lambda i, sel: (jnp.maximum(sel[i], 0), 0)),
+            pl.BlockSpec(tile, lambda i, sel: (jnp.maximum(sel[i], 0), 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, c), lambda i, sel: (i, 0)),
+        out_specs=pl.BlockSpec(tile, lambda i, sel: (i, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, c), base.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, c // LANES, LANES), base.dtype),
         interpret=interpret,
-    )(sel, base, diff)
+    )(sel, base.reshape(n, c // LANES, LANES),
+      diff.reshape(diff.shape[0], c // LANES, LANES))
+    return out.reshape(n, c)

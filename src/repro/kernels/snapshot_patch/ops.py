@@ -12,7 +12,7 @@ from .ref import patch_apply_ref
 
 @functools.partial(jax.jit, static_argnames=("mode", "scale", "interpret", "use_kernel"))
 def patch_apply_op(base, diff, sel, *, mode: str = "replace", scale: float = 1.0,
-                   interpret: bool = True, use_kernel: bool = True):
+                   interpret: bool = False, use_kernel: bool = True):
     if use_kernel:
         return patch_apply(base, diff, sel, mode=mode, scale=scale,
                            interpret=interpret)

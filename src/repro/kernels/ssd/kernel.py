@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
-
 
 def _kernel(
     x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref,  # inputs
@@ -115,7 +113,7 @@ def ssd_scan(
             jax.ShapeDtypeStruct((b, nh, hd, ds), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hd, ds), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -11,7 +11,7 @@ from .ref import ssd_ref
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret", "use_kernel"))
-def ssd_op(x, dt, A, B, C, D, *, chunk: int = 256, interpret: bool = True,
+def ssd_op(x, dt, A, B, C, D, *, chunk: int = 256, interpret: bool = False,
            use_kernel: bool = True):
     """Returns (y (b,l,nh,hd), final_state (b,nh,hd,ds))."""
     if use_kernel:

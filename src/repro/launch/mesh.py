@@ -8,6 +8,7 @@ device initialization.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -17,7 +18,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     adds a leading "pod" axis (DP across pods — the slow DCN dimension)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(*, data: int = 2, model: int = 2):
@@ -27,4 +28,5 @@ def make_host_mesh(*, data: int = 2, model: int = 2):
     if data * model > n:
         model = 1
         data = n
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

@@ -20,16 +20,19 @@ generates a seeded arrival trace (``poisson``/``mmpp``/``diurnal``/
 ``azure``), replays it through the admission layer (bounded per-worker
 queues, concurrency caps, overload shedding) at real arrival times, and
 prints the p50/p95/p99 end-to-end latency split into queueing delay vs
-cold-start boot vs execution, plus shed counts and fleet metrics.
+cold-start boot vs execution, plus shed counts and fleet metrics.  The
+exit code is 1 when any replayed request failed.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
+import sys
 import tempfile
 
 from repro.configs import get_config, reduced
+from repro.launch.cache import enable_compilation_cache
 from repro.models import build_model
 from repro.serving import (
     AdmissionConfig,
@@ -58,7 +61,7 @@ def _parse_autoscale(value: str) -> AutoscaleConfig:
         ) from None
 
 
-def main() -> None:
+def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--family", default="gemma-2b")
     ap.add_argument("--functions", type=int, default=4)
@@ -97,8 +100,9 @@ def main() -> None:
                     help="trace mode: autoscale the worker fleet between "
                          "MIN and MAX during the replay (starts at MIN)")
     ap.add_argument("--root", default=None)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
+    enable_compilation_cache()
     root = args.root or tempfile.mkdtemp(prefix="repro_serve_")
     cfg = reduced(get_config(args.family))
     model = build_model(cfg)
@@ -138,7 +142,7 @@ def main() -> None:
         print(json.dumps({"trace_serving": report.summary()}, indent=1))
         print(json.dumps({"scheduler": fleet["scheduler"]}, indent=1))
         print(json.dumps({"serving": fleet["serving"]}, indent=1))
-        return
+        return 1 if report.n_failed else 0
 
     strategies = args.strategies or ["regular", "reap", "seuss", "snapfaas-",
                                      "snapfaas", "auto"]
@@ -161,7 +165,8 @@ def main() -> None:
             print(f"snapfaas speedup over {other} (cold e2e): {sp:.2f}x")
     if "auto" in base and base["auto"].get("resolved"):
         print(f"auto resolved to: {base['auto']['resolved']}")
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -22,6 +22,7 @@ import jax
 
 from repro.configs import get_config, reduced
 from repro.data.pipeline import ShardedLoader
+from repro.launch.cache import enable_compilation_cache
 from repro.models import build_model
 from repro.optim import OptimizerConfig
 from repro.train.trainer import Trainer, TrainerConfig
@@ -45,6 +46,7 @@ def main() -> None:
                     help="simulate a slow peer loader and steal its shard")
     args = ap.parse_args()
 
+    enable_compilation_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)

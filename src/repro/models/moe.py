@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.distrib.act import batch_shards, current_binding, shard
-from repro.distrib.compat import shard_map
 
 from .layers import activation
 
@@ -264,7 +263,7 @@ def moe_ffn_sharded(
     args = [x, params["router"], params["w_in"],
             params["w_gate"] if gated else params["w_in"], params["w_out"]]
     in_specs = (x_spec, r_spec, w_in_spec, w_in_spec, w_out_spec)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         inner, mesh=mesh, in_specs=in_specs,
         out_specs=(x_spec, P_()), check_vma=False,
     )(*args)

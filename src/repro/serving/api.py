@@ -213,19 +213,28 @@ class SourceResolver(Protocol):
 @dataclass
 class NpzSourceResolver:
     """Default :class:`SourceResolver`: ``npz`` artifacts on disk, with
-    in-memory fallbacks for functions registered without files."""
+    in-memory fallbacks for functions registered without files.
+
+    The npz format records extension dtypes such as bfloat16 as raw bytes
+    (``|V2``); ``dtypes`` names each path's real dtype, and loaded arrays
+    are viewed back as it."""
 
     source_path: str = ""
     base_path: str = ""
     source_fallback: Optional[Callable[[], Dict[str, np.ndarray]]] = None
     base_fallback: Optional[Callable[[], Dict[str, np.ndarray]]] = None
+    dtypes: Dict[str, str] = field(default_factory=dict)
+
+    def _typed(self, path: str, arr: np.ndarray) -> np.ndarray:
+        want = self.dtypes.get(path)
+        return arr if want is None or arr.dtype == want else arr.view(want)
 
     def load_source(self) -> Dict[str, np.ndarray]:
         import os
 
         if self.source_path and os.path.exists(self.source_path):
             with np.load(self.source_path) as z:
-                return {k: z[k] for k in z.files}
+                return {k: self._typed(k, z[k]) for k in z.files}
         if self.source_fallback is not None:
             return self.source_fallback()
         raise FileNotFoundError(self.source_path or "<no source declared>")
@@ -235,7 +244,8 @@ class NpzSourceResolver:
 
         if self.base_path and os.path.exists(self.base_path):
             with np.load(self.base_path) as z:
-                return {k.replace("|", "/"): z[k] for k in z.files}
+                arrays = {k.replace("|", "/"): z[k] for k in z.files}
+            return {p: self._typed(p, a) for p, a in arrays.items()}
         if self.base_fallback is not None:
             return self.base_fallback()
         raise FileNotFoundError(self.base_path or "<no base image declared>")

@@ -44,6 +44,8 @@ def build_specs(
         kind = kinds[i % len(kinds)]
         variant = {k: np.array(v) for k, v in base_flat.items()}
         touched_rows: Dict[str, List[int]] = {}
+        # edits are in place: ``bf16_array + 0.01`` would promote to float32,
+        # and a variant must keep its family's dtypes to be served
         if kind == "adapter":
             rows = list(range(8 * i, 8 * i + 16))
             variant["embed/table"][rows] += rng.standard_normal(
@@ -52,13 +54,13 @@ def build_specs(
             touched_rows["embed/table"] = rows
             # one block's w_in as the "imported library"
             key = next(k for k in variant if k.endswith("ffn/w_in"))
-            variant[key] = variant[key] + 0.01
+            variant[key] += 0.01
         elif kind == "head":
-            variant["embed/table"] = variant["embed/table"] * 1.01  # full table
+            variant["embed/table"] *= 1.01  # full table
         else:  # finetune
             for k in variant:
                 if "/wq" in k or "/w_in" in k or "/w_out" in k:
-                    variant[k] = variant[k] + 0.005
+                    variant[k] += 0.005
         src = os.path.join(src_dir, f"fn{i}.npz")
         np.savez(src, **{k: v for k, v in variant.items()
                          if not np.array_equal(v, base_flat[k])})

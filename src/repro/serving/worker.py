@@ -241,12 +241,15 @@ class Worker:
         pool = self.registry.pools[spec.family]
         base = self.registry.bases[spec.family]
         own = spec.delta if spec.delta is not None else spec.variant
+        dtypes = {p: m.dtype for p, m in base.arrays.items()}
+        dtypes.update((p, str(v.dtype)) for p, v in own.items())
         return NpzSourceResolver(
             source_path=spec.source_path,
             base_path=self._base_npz.get(spec.family, ""),
             source_fallback=lambda: {k: np.array(v) for k, v in own.items()},
             base_fallback=lambda: {p: np.array(pool.get(p))
                                    for p in base.arrays},
+            dtypes=dtypes,
         )
 
     # -- planner glue (Strategy.AUTO) ----------------------------------------
@@ -337,10 +340,10 @@ class Worker:
         flat = base_dev.reshape(-1)
         if n * c != total:  # partial tail chunk: pad base, slice after
             flat = jnp.pad(flat, (0, n * c - total))
-        on_tpu = jax.default_backend() == "tpu"
+        # the Pallas kernel on the chip; its jnp oracle elsewhere
         out = patch_apply_op(
             flat.reshape(n, c), diff2d, jnp.asarray(ma.patch.sel),
-            mode="replace", interpret=not on_tpu, use_kernel=on_tpu,
+            mode="replace", use_kernel=jax.default_backend() == "tpu",
         )
         out = out.reshape(-1)[:total].reshape(meta.shape)
         ma._dev = out

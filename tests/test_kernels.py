@@ -139,6 +139,13 @@ class TestSnapshotPatch:
         ref = patch_apply_ref(base, diff, sel, mode="add", scale=0.5)
         np.testing.assert_allclose(out, ref, rtol=1e-6, atol=1e-6)
 
+    def test_chunk_not_multiple_of_lanes_raises(self):
+        base = jnp.zeros((4, 96), jnp.float32)
+        diff = jnp.ones((1, 96), jnp.float32)
+        sel = jnp.asarray([0, -1, -1, -1], jnp.int32)
+        with pytest.raises(ValueError, match="multiple of 128"):
+            patch_apply(base, diff, sel, interpret=True)
+
     def test_restore_equivalence_with_chunkstore(self, tmp_path):
         """End-to-end: kernel patch-apply reproduces the host restore path."""
         from repro.core import ChunkStore, take_diff_snapshot, take_snapshot, resolve

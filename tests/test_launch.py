@@ -32,7 +32,8 @@ def f(w, x):
         return jnp.tanh(c @ w), None
     y, _ = jax.lax.scan(body, x, None, length=7)
     return y
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 comp = jax.jit(f, in_shardings=(NamedSharding(mesh, P("data", "model")),
                                 NamedSharding(mesh, P(None, "data")))).lower(
     jax.ShapeDtypeStruct((256, 256), jnp.float32),
@@ -74,7 +75,8 @@ from repro.launch.specs import build_cell
 from repro.models.config import SHAPES
 cfg = reduced(get_config({arch!r}))
 shape = dataclasses.replace(SHAPES[{shape!r}], seq_len=256, global_batch=8)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 with mesh:
     cell = build_cell(cfg, shape, mesh, loss_chunk=64)
     compiled = jax.jit(cell.fn, in_shardings=cell.in_shardings,
@@ -101,7 +103,8 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import ARCHS, get_config
 from repro.distrib.sharding import Rules
 from repro.models import build_model
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 for arch in ARCHS:
     cfg = get_config(arch)
     model = build_model(cfg)
@@ -127,3 +130,94 @@ print("OK")
         axis = "model" if dim % msize == 0 else None
         if axis is not None:
             assert dim % msize == 0
+
+
+def _load_replay_cli():
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "launch_serve_cli", os.path.join(REPO, "launch", "serve.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+class TestServeExitCode:
+    """Both serve CLIs exit non-zero when a replayed request failed, unless
+    a chaos profile made failures the expected outcome."""
+
+    TRACE = ["--rps", "10", "--duration", "0.5", "--functions", "2",
+             "--workers", "1", "--time-scale", "0"]
+
+    @pytest.fixture
+    def failing(self, monkeypatch):
+        from repro.serving.worker import Worker
+
+        def arm():
+            def broken(self, request):
+                raise RuntimeError("injected handler failure")
+            monkeypatch.setattr(Worker, "invoke", broken)
+        return arm
+
+    @pytest.mark.parametrize("fail,expected", [(False, 0), (True, 1)])
+    def test_strategy_cli_trace_mode(self, tmp_path, monkeypatch, failing,
+                                     fail, expected):
+        from repro.launch import serve
+
+        monkeypatch.setattr(serve, "enable_compilation_cache", lambda: "")
+        if fail:
+            failing()
+        rc = serve.main(["--trace", "poisson", "--root", str(tmp_path),
+                         *self.TRACE])
+        assert rc == expected
+
+    @pytest.mark.parametrize("fail,chaos,expected", [
+        (False, None, 0), (True, None, 1), (True, "lossy-disk", 0),
+    ])
+    def test_replay_cli(self, tmp_path, monkeypatch, failing, fail, chaos,
+                        expected):
+        cli = _load_replay_cli()
+        monkeypatch.setattr(cli, "enable_compilation_cache", lambda: "")
+        if fail:
+            failing()
+        argv = ["--root", str(tmp_path), *self.TRACE]
+        if chaos is not None:
+            argv += ["--chaos", chaos]
+        assert cli.main(argv) == expected
+
+
+class TestCompilationCache:
+    """An explicit JAX_COMPILATION_CACHE_DIR is left to JAX; otherwise the
+    cache is the fixed repository path and keeps every compile."""
+
+    KEYS = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs")
+
+    @pytest.fixture
+    def jax_config(self):
+        import jax
+
+        prev = {k: getattr(jax.config, k) for k in self.KEYS}
+        yield jax.config
+        for k, v in prev.items():
+            jax.config.update(k, v)
+
+    @pytest.mark.parametrize("env_dir", [None, "/elsewhere/cache"])
+    def test_placement(self, monkeypatch, jax_config, env_dir):
+        from repro.launch import cache
+
+        if env_dir is None:
+            monkeypatch.delenv(cache.ENV_VAR, raising=False)
+        else:
+            monkeypatch.setenv(cache.ENV_VAR, env_dir)
+        before = {k: getattr(jax_config, k) for k in self.KEYS}
+        got = cache.enable_compilation_cache()
+        after = {k: getattr(jax_config, k) for k in self.KEYS}
+        if env_dir is None:
+            assert got == str(cache.REPO_CACHE_DIR)
+            assert str(cache.REPO_CACHE_DIR.parent) == os.path.realpath(REPO)
+            assert after == {"jax_compilation_cache_dir": got,
+                             "jax_persistent_cache_min_compile_time_secs": 0.0}
+        else:
+            assert got == env_dir
+            assert after == before
