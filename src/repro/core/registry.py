@@ -22,7 +22,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from .chunkstore import DEFAULT_CHUNK_BYTES, ChunkRef, ChunkStore
-from .metrics import ColdStartMetrics
+from .metrics import ColdStartMetrics, span
 from .planner import SnapshotSizes, StorageModel
 from .tiers import PrefetchStats, TieredChunkStore, TierSpec
 from .restore import (
@@ -523,10 +523,11 @@ class ZygoteRegistry:
         prefetch and everything materializes lazily (cached separately).
         """
         rec = self.functions[name]
-        with rec.plan_lock:
-            return self._restore_plan_locked(
-                rec, name, strategy, demand_paged=demand_paged
-            )
+        with span("restore.plan"):
+            with rec.plan_lock:
+                return self._restore_plan_locked(
+                    rec, name, strategy, demand_paged=demand_paged
+                )
 
     def _restore_plan_locked(
         self, rec: FunctionRecord, name: str, strategy: str,

@@ -33,7 +33,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Set, Tuple
 import numpy as np
 
 from .chunkstore import ChunkRef, ChunkStore
-from .metrics import ColdStartMetrics, timer
+from .metrics import ColdStartMetrics, span
 from .snapshot import ArrayMeta, ResolvedArray, SnapshotManifest, resolve
 from .workingset import AccessLog, WorkingSet
 
@@ -440,43 +440,45 @@ def restore_layered(
     """
     strategy = "snapfaas" if working_set is not None else "snapfaas-"
     m = ColdStartMetrics(strategy=strategy, function=function)
-    t = timer()
 
     # A: resolve layering, restore device state, set up instance bookkeeping.
-    resolved = resolve(base, diff)
-    device_state = dict(base.device_state)
-    device_state.update(diff.device_state)
-    m.t_preconfig = t.lap()
+    with span("restore.alloc") as s:
+        resolved = resolve(base, diff)
+        device_state = dict(base.device_state)
+        device_state.update(diff.device_state)
+    m.t_preconfig = s.seconds
 
     # B: one batched (readv-style) eager read of the chosen diff chunks.
-    eager_keys: List[Tuple[Path, int, ChunkRef]] = []
-    for path, ra in resolved.items():
-        for idx in ra.dirty_indices():
-            _, ref = ra.sources[idx]
-            if ref.zero:
-                continue
-            if working_set is None or (path, idx) in working_set:
-                eager_keys.append((path, idx, ref))
-    payloads = store.read_batch([r for _, _, r in eager_keys])
-    eager_set: Optional[Set[Tuple[Path, int]]] = (
-        {(p, i) for p, i, _ in eager_keys} if working_set is not None else None
-    )
+    with span("restore.eager") as s:
+        eager_keys: List[Tuple[Path, int, ChunkRef]] = []
+        for path, ra in resolved.items():
+            for idx in ra.dirty_indices():
+                _, ref = ra.sources[idx]
+                if ref.zero:
+                    continue
+                if working_set is None or (path, idx) in working_set:
+                    eager_keys.append((path, idx, ref))
+        payloads = store.read_batch([r for _, _, r in eager_keys])
+        eager_set: Optional[Set[Tuple[Path, int]]] = (
+            {(p, i) for p, i, _ in eager_keys} if working_set is not None else None
+        )
 
-    arrays: Dict[Path, MaterializedArray] = {}
-    for path, ra in resolved.items():
-        if not ra.dirty_indices():
-            arrays[path] = MaterializedArray.shared(path, ra.meta, pool.get(path))
-            m.shared_bytes_mapped += ra.meta.nbytes
-        else:
-            arrays[path] = _assemble_private(store, pool, path, ra, payloads, eager_set)
-    m.t_eager = t.lap()
+        arrays: Dict[Path, MaterializedArray] = {}
+        for path, ra in resolved.items():
+            if not ra.dirty_indices():
+                arrays[path] = MaterializedArray.shared(path, ra.meta, pool.get(path))
+                m.shared_bytes_mapped += ra.meta.nbytes
+            else:
+                arrays[path] = _assemble_private(store, pool, path, ra, payloads, eager_set)
+    m.t_eager = s.seconds
     m.eager_bytes = sum(r.size for _, _, r in eager_keys)
     m.eager_chunks = len(eager_keys)
 
     # C: residual, un-memoizable initialization.
-    if residual_init is not None:
-        device_state = residual_init(device_state)
-    m.t_init = t.lap()
+    with span("restore.init") as s:
+        if residual_init is not None:
+            device_state = residual_init(device_state)
+    m.t_init = s.seconds
 
     return RestoredInstance(
         function=function, strategy=strategy, arrays=arrays,
@@ -498,31 +500,33 @@ def restore_reap(
     (eagerly or on fault) — the fundamental cost the paper's Fig. 6 shows.
     """
     m = ColdStartMetrics(strategy="reap", function=function)
-    t = timer()
-    resolved = resolve(None, full)  # every chunk reads as "diff" (unique)
-    device_state = dict(full.device_state)
-    m.t_preconfig = t.lap()
+    with span("restore.alloc") as s:
+        resolved = resolve(None, full)  # every chunk reads as "diff" (unique)
+        device_state = dict(full.device_state)
+    m.t_preconfig = s.seconds
 
-    eager_keys: List[Tuple[Path, int, ChunkRef]] = []
-    for path, ra in resolved.items():
-        for idx, (_, ref) in enumerate(ra.sources):
-            if ref.zero:
-                continue
-            if working_set is None or (path, idx) in working_set:
-                eager_keys.append((path, idx, ref))
-    payloads = store.read_batch([r for _, _, r in eager_keys])
-    eager_set = {(p, i) for p, i, _ in eager_keys}
-    arrays = {
-        path: _assemble_private(store, None, path, ra, payloads, eager_set)
-        for path, ra in resolved.items()
-    }
-    m.t_eager = t.lap()
+    with span("restore.eager") as s:
+        eager_keys: List[Tuple[Path, int, ChunkRef]] = []
+        for path, ra in resolved.items():
+            for idx, (_, ref) in enumerate(ra.sources):
+                if ref.zero:
+                    continue
+                if working_set is None or (path, idx) in working_set:
+                    eager_keys.append((path, idx, ref))
+        payloads = store.read_batch([r for _, _, r in eager_keys])
+        eager_set = {(p, i) for p, i, _ in eager_keys}
+        arrays = {
+            path: _assemble_private(store, None, path, ra, payloads, eager_set)
+            for path, ra in resolved.items()
+        }
+    m.t_eager = s.seconds
     m.eager_bytes = sum(r.size for _, _, r in eager_keys)
     m.eager_chunks = len(eager_keys)
 
-    if residual_init is not None:
-        device_state = residual_init(device_state)
-    m.t_init = t.lap()
+    with span("restore.init") as s:
+        if residual_init is not None:
+            device_state = residual_init(device_state)
+    m.t_init = s.seconds
     return RestoredInstance(
         function=function, strategy="reap", arrays=arrays,
         device_state=device_state, metrics=m,
@@ -542,27 +546,28 @@ def restore_seuss(
     *source* — i.e. pay function initialization compute instead of restoring
     a diff snapshot (the cost SEUSS-style designs cannot memoize)."""
     m = ColdStartMetrics(strategy="seuss", function=function)
-    t = timer()
-    device_state = dict(base.device_state)
-    arrays: Dict[Path, MaterializedArray] = {}
-    for path, meta in base.arrays.items():
-        arrays[path] = MaterializedArray.shared(path, meta, pool.get(path))
-        m.shared_bytes_mapped += meta.nbytes
-    m.t_preconfig = t.lap()
+    with span("restore.alloc") as s:
+        device_state = dict(base.device_state)
+        arrays: Dict[Path, MaterializedArray] = {}
+        for path, meta in base.arrays.items():
+            arrays[path] = MaterializedArray.shared(path, meta, pool.get(path))
+            m.shared_bytes_mapped += meta.nbytes
+    m.t_preconfig = s.seconds
     m.t_eager = 0.0  # SEUSS restores memory by mmap only (constant, ~0) — §6.3 B
 
     # C: function import & init from source (measured, not memoized).
-    loaded = source_loader()
-    for path, arr in loaded.items():
-        meta = ArrayMeta(shape=tuple(arr.shape), dtype=str(arr.dtype),
-                         chunk_bytes=base.arrays[path].chunk_bytes if path in base.arrays
-                         else 256 * 1024, chunks=[])
-        ma = MaterializedArray(path, meta)
-        ma._arr = arr
-        arrays[path] = ma
-    if residual_init is not None:
-        device_state = residual_init(device_state)
-    m.t_init = t.lap()
+    with span("restore.init") as s:
+        loaded = source_loader()
+        for path, arr in loaded.items():
+            meta = ArrayMeta(shape=tuple(arr.shape), dtype=str(arr.dtype),
+                             chunk_bytes=base.arrays[path].chunk_bytes if path in base.arrays
+                             else 256 * 1024, chunks=[])
+            ma = MaterializedArray(path, meta)
+            ma._arr = arr
+            arrays[path] = ma
+        if residual_init is not None:
+            device_state = residual_init(device_state)
+    m.t_init = s.seconds
     return RestoredInstance(
         function=function, strategy="seuss", arrays=arrays,
         device_state=device_state, metrics=m,
@@ -579,26 +584,26 @@ def restore_regular(
     """No snapshots: full environment + function initialization from source
     (the boot-from-kernel baseline the paper normalizes against)."""
     m = ColdStartMetrics(strategy="regular", function=function)
-    t = timer()
-    m.t_preconfig = t.lap()
-    base_arrays = base_loader()       # "boot the runtime": load base weights
-    arrays: Dict[Path, MaterializedArray] = {}
-    for path, arr in base_arrays.items():
-        meta = ArrayMeta(tuple(arr.shape), str(arr.dtype), 256 * 1024, [])
-        ma = MaterializedArray(path, meta)
-        ma._arr = arr
-        arrays[path] = ma
-    m.t_eager = t.lap()               # B: bulk state load from storage
-    loaded = source_loader()          # C: function import/init
-    for path, arr in loaded.items():
-        meta = ArrayMeta(tuple(arr.shape), str(arr.dtype), 256 * 1024, [])
-        ma = MaterializedArray(path, meta)
-        ma._arr = arr
-        arrays[path] = ma
-    device_state: Dict[str, Any] = {}
-    if residual_init is not None:
-        device_state = residual_init(device_state)
-    m.t_init = t.lap()
+    with span("restore.eager") as s:  # B: bulk state load from storage
+        base_arrays = base_loader()       # "boot the runtime": load base weights
+        arrays: Dict[Path, MaterializedArray] = {}
+        for path, arr in base_arrays.items():
+            meta = ArrayMeta(tuple(arr.shape), str(arr.dtype), 256 * 1024, [])
+            ma = MaterializedArray(path, meta)
+            ma._arr = arr
+            arrays[path] = ma
+    m.t_eager = s.seconds
+    with span("restore.init") as s:   # C: function import/init
+        loaded = source_loader()
+        for path, arr in loaded.items():
+            meta = ArrayMeta(tuple(arr.shape), str(arr.dtype), 256 * 1024, [])
+            ma = MaterializedArray(path, meta)
+            ma._arr = arr
+            arrays[path] = ma
+        device_state: Dict[str, Any] = {}
+        if residual_init is not None:
+            device_state = residual_init(device_state)
+    m.t_init = s.seconds
     return RestoredInstance(
         function=function, strategy="regular", arrays=arrays,
         device_state=device_state, metrics=m,

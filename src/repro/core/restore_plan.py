@@ -32,7 +32,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Set, Tuple
 import numpy as np
 
 from .chunkstore import ChunkRef, ChunkStore
-from .metrics import ColdStartMetrics, timer
+from .metrics import ColdStartMetrics, span
 from .restore import ArrayPatch, BasePool, MaterializedArray, RestoredInstance
 from .snapshot import ArrayMeta, SnapshotManifest, resolve
 from .workingset import WorkingSet
@@ -275,39 +275,39 @@ def execute_restore_plan(
     are promoted downward (None → the store's configured default).
     """
     m = ColdStartMetrics(strategy=plan.strategy, function=plan.function)
-    t = timer()
 
     # A: allocate every private buffer up front and wire the instance.
-    arrays: Dict[Path, MaterializedArray] = {}
-    dests: List[Tuple[ChunkRef, memoryview]] = []
-    for pa in plan.arrays:
-        if pa.shared:
-            assert pool is not None
-            arrays[pa.path] = MaterializedArray.shared(
-                pa.path, pa.meta, pool.get(pa.path)
+    with span("restore.alloc") as s:
+        arrays: Dict[Path, MaterializedArray] = {}
+        dests: List[Tuple[ChunkRef, memoryview]] = []
+        for pa in plan.arrays:
+            if pa.shared:
+                assert pool is not None
+                arrays[pa.path] = MaterializedArray.shared(
+                    pa.path, pa.meta, pool.get(pa.path)
+                )
+                continue
+            buf = np.zeros(pa.meta.nbytes, dtype=np.uint8)
+            ma = MaterializedArray.private(
+                pa.path, pa.meta, buf, list(pa.pending), store, pool,
+                recorded=pa.recorded,
             )
-            continue
-        buf = np.zeros(pa.meta.nbytes, dtype=np.uint8)
-        ma = MaterializedArray.private(
-            pa.path, pa.meta, buf, list(pa.pending), store, pool,
-            recorded=pa.recorded,
-        )
-        if pa.patch_sel is not None:
-            rows = np.zeros(pa.patch_rows * pa.meta.chunk_bytes, dtype=np.uint8)
-            ma.patch = ArrayPatch(
-                sel=pa.patch_sel, rows=rows,
-                row_of=pa.patch_row_of or {}, chunk_bytes=pa.meta.chunk_bytes,
-            )
-            mv_rows = memoryview(rows)
-            for off, ref in pa.patch_eager:
-                dests.append((ref, mv_rows[off : off + ref.size]))
-        if pa.eager:
-            mv = memoryview(buf)
-            for off, ref in pa.eager:
-                dests.append((ref, mv[off : off + ref.size]))
-        arrays[pa.path] = ma
-    m.shared_bytes_mapped = plan.shared_bytes
-    m.t_preconfig = t.lap()
+            if pa.patch_sel is not None:
+                rows = np.zeros(pa.patch_rows * pa.meta.chunk_bytes, dtype=np.uint8)
+                ma.patch = ArrayPatch(
+                    sel=pa.patch_sel, rows=rows,
+                    row_of=pa.patch_row_of or {}, chunk_bytes=pa.meta.chunk_bytes,
+                )
+                mv_rows = memoryview(rows)
+                for off, ref in pa.patch_eager:
+                    dests.append((ref, mv_rows[off : off + ref.size]))
+            if pa.eager:
+                mv = memoryview(buf)
+                for off, ref in pa.eager:
+                    dests.append((ref, mv[off : off + ref.size]))
+            arrays[pa.path] = ma
+        m.shared_bytes_mapped = plan.shared_bytes
+    m.t_preconfig = s.seconds
 
     # B (demand-paged): stream nothing — kick off a background prefetch of
     # the recorded set through the tiered store's pipelined stages and let
@@ -316,60 +316,65 @@ def execute_restore_plan(
     # synchronously through the verified ``get_chunk`` path, so a failed or
     # slow prefetch can delay but never corrupt.
     if plan.demand_paged:
-        m.demand_paged = True
-        m.prefetch_bytes = plan.prefetch_bytes
-        inst = RestoredInstance(
-            function=plan.function, strategy=plan.strategy, arrays=arrays,
-            device_state=dict(plan.device_state), metrics=m,
-        )
-        if plan.prefetch_refs and hasattr(store, "prefetch"):
-            import threading
-
-            refs = list(plan.prefetch_refs)
-
-            def _bg() -> None:
-                try:
-                    store.prefetch(refs)
-                except Exception:  # broad-ok: best-effort background warming must never kill the worker
-                    pass  # misses fault in verified demand reads later
-
-            th = threading.Thread(
-                target=_bg, name=f"ws-prefetch-{plan.function}", daemon=True
+        with span("restore.eager") as s:
+            m.demand_paged = True
+            m.prefetch_bytes = plan.prefetch_bytes
+            inst = RestoredInstance(
+                function=plan.function, strategy=plan.strategy, arrays=arrays,
+                device_state=dict(plan.device_state), metrics=m,
             )
-            th.start()
-            inst.prefetch_thread = th
-        m.t_eager = t.lap()
-        if residual_init is not None:
-            inst.device_state = residual_init(inst.device_state)
-        m.t_init = t.lap()
+            if plan.prefetch_refs and hasattr(store, "prefetch"):
+                import threading
+
+                refs = list(plan.prefetch_refs)
+
+                def _bg() -> None:
+                    try:
+                        store.prefetch(refs)
+                    except Exception:  # broad-ok: best-effort background warming must never kill the worker
+                        pass  # misses fault in verified demand reads later
+
+                th = threading.Thread(
+                    target=_bg, name=f"ws-prefetch-{plan.function}", daemon=True
+                )
+                th.start()
+                inst.prefetch_thread = th
+        m.t_eager = s.seconds
+        with span("restore.init") as s:
+            if residual_init is not None:
+                inst.device_state = residual_init(inst.device_state)
+        m.t_init = s.seconds
         return inst
 
     # B: one batched parallel scatter-read, straight into the buffers.
     # Tiered stores pipeline remote fetch / local preadv / RAM memcpy and
     # report the per-tier split; flat stores take the plain path.
-    if hasattr(store, "tier_stats"):
-        from .tiers import TierReadStats
+    with span("restore.eager") as s:
+        if hasattr(store, "tier_stats"):
+            from .tiers import TierReadStats
 
-        stats = TierReadStats()
-        store.read_batch_into(dests, stats=stats, promote=promote)
-        m.tier_chunks = stats.tier_chunks
-        m.tier_bytes = stats.tier_bytes
-        m.remote_fetch_s = stats.remote_fetch_s
-        m.promoted_bytes = stats.promoted_bytes
-        m.read_retries = stats.retries
-        m.repaired_chunks = stats.repaired_chunks
-    else:
-        store.read_batch_into(dests)
-    m.t_eager = t.lap()
+            stats = TierReadStats()
+            store.read_batch_into(dests, stats=stats, promote=promote)
+            m.tier_chunks = stats.tier_chunks
+            m.tier_bytes = stats.tier_bytes
+            m.remote_fetch_s = stats.remote_fetch_s
+            m.promoted_bytes = stats.promoted_bytes
+            m.read_retries = stats.retries
+            m.repaired_chunks = stats.repaired_chunks
+        else:
+            with span("restore.read", bytes=plan.eager_bytes):
+                store.read_batch_into(dests)
+    m.t_eager = s.seconds
     m.eager_bytes = plan.eager_bytes
     m.eager_chunks = plan.eager_chunks
     m.eager_unique_bytes = plan.unique_eager_bytes
 
     # C: residual, un-memoizable initialization.
-    device_state = dict(plan.device_state)
-    if residual_init is not None:
-        device_state = residual_init(device_state)
-    m.t_init = t.lap()
+    with span("restore.init") as s:
+        device_state = dict(plan.device_state)
+        if residual_init is not None:
+            device_state = residual_init(device_state)
+    m.t_init = s.seconds
 
     return RestoredInstance(
         function=plan.function, strategy=plan.strategy, arrays=arrays,

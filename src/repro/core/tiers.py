@@ -67,6 +67,7 @@ from .faults import (
     TierReadError,
     TierUnavailableError,
 )
+from .metrics import span
 
 # RAM-tier reads above this size fan the memcpys across the I/O pool:
 # fresh destination buffers page-fault on first write, and parallel copies
@@ -1202,137 +1203,140 @@ class TieredChunkStore:
         """
         if promote is None:
             promote = self.spec.promote_on_fetch
-        primary: Dict[str, memoryview] = {}
-        dup: List[Tuple[str, memoryview]] = []
-        ram_items: List[Tuple[ChunkRef, memoryview, bytes]] = []
-        local_items: List[Tuple[ChunkRef, memoryview]] = []
-        remote_items: List[Tuple[ChunkRef, memoryview]] = []
-        for ref, buf in dests:
-            if ref.zero:
-                continue
-            view = memoryview(buf)
-            if view.ndim != 1 or view.itemsize != 1:
-                view = view.cast("B")
-            if len(view) != ref.size:
-                raise ValueError(
-                    f"dest for {ref.digest} has {len(view)} bytes, "
-                    f"want {ref.size}"
-                )
-            if ref.digest in primary:
-                dup.append((ref.digest, view))
-                continue
-            primary[ref.digest] = view
-            # classification grabs the RAM payload immediately so a
-            # concurrent eviction cannot strand the read
-            payload = self.ram.get(ref.digest)
-            if payload is not None:
-                ram_items.append((ref, view, payload))
-            elif ref.digest in self.local:
-                local_items.append((ref, view))
-            elif self._remote is not None and self._remote.has(ref.digest):
-                remote_items.append((ref, view))
-            else:
-                raise KeyError(ref.digest)  # keyerror-ok: absent from every tier = reclaimed, same contract as get_chunk
-
-        total = 0
-        remote_future: Optional[Future] = None
-        t_remote = 0.0
-        local_fallback = False
-        if remote_items:
-            remote_future = _get_fetch_pool().submit(
-                self._remote_read, remote_items, stats=stats
-            )
-            t_remote = time.perf_counter()
-        if local_items:
-            try:
-                total += self._local_read(
-                    local_items, parallel=parallel,
-                    coalesce_gap=coalesce_gap, stats=stats,
-                )
-            except KeyError:
-                # a concurrent demote() moved chunks between classification
-                # and the read — re-classify and re-dispatch the batch
-                # through the full hierarchy (idempotent: overwrites any
-                # partial fills; keeps batching, promote and stats honest)
-                local_fallback = True
-                total += self.read_batch_into(
-                    local_items, parallel=parallel,
-                    coalesce_gap=coalesce_gap, stats=stats, promote=promote,
-                )
-            except TierReadError:
-                # the pack medium kept failing past the retry budget —
-                # heal chunk by chunk from the other tiers / base sources
-                local_fallback = True
-                for ref, view in local_items:
-                    self._recover_chunk(ref, view, "local",
-                                        corrupt=False, stats=stats)
-                total += sum(r.size for r, _ in local_items)
-        ram_bytes = sum(len(p) for _, _, p in ram_items)
-        if parallel and ram_bytes > _RAM_PARALLEL_BYTES and len(ram_items) > 1:
-            # ctypes.memmove releases the GIL, so fanned-out copies overlap
-            # the page faults fresh destination buffers take on first write
-            # (memoryview slice-assign holds the GIL and cannot)
-            nshards = min(8, len(ram_items))
-            shards = [ram_items[i::nshards] for i in range(nshards)]
-
-            def _copy(shard):
-                for _, view, payload in shard:
-                    ctypes.memmove(
-                        ctypes.addressof(ctypes.c_char.from_buffer(view)),
-                        payload, len(payload),
+        # the streams, up to verification; dup copies follow it
+        with span("restore.read",
+                  bytes=sum(r.size for r, _ in dests if not r.zero)):
+            primary: Dict[str, memoryview] = {}
+            dup: List[Tuple[str, memoryview]] = []
+            ram_items: List[Tuple[ChunkRef, memoryview, bytes]] = []
+            local_items: List[Tuple[ChunkRef, memoryview]] = []
+            remote_items: List[Tuple[ChunkRef, memoryview]] = []
+            for ref, buf in dests:
+                if ref.zero:
+                    continue
+                view = memoryview(buf)
+                if view.ndim != 1 or view.itemsize != 1:
+                    view = view.cast("B")
+                if len(view) != ref.size:
+                    raise ValueError(
+                        f"dest for {ref.digest} has {len(view)} bytes, "
+                        f"want {ref.size}"
                     )
+                if ref.digest in primary:
+                    dup.append((ref.digest, view))
+                    continue
+                primary[ref.digest] = view
+                # classification grabs the RAM payload immediately so a
+                # concurrent eviction cannot strand the read
+                payload = self.ram.get(ref.digest)
+                if payload is not None:
+                    ram_items.append((ref, view, payload))
+                elif ref.digest in self.local:
+                    local_items.append((ref, view))
+                elif self._remote is not None and self._remote.has(ref.digest):
+                    remote_items.append((ref, view))
+                else:
+                    raise KeyError(ref.digest)  # keyerror-ok: absent from every tier = reclaimed, same contract as get_chunk
 
-            list(_get_io_pool().map(_copy, shards))
-        else:
-            for _, view, payload in ram_items:
-                view[:] = payload
-        total += ram_bytes
-        promoting_bytes = 0
-        remote_fallback = False
-        if remote_future is not None:
-            try:
-                total += remote_future.result()
-            except KeyError as exc:
-                # the remote index changed between classification and the
-                # read (e.g. a racing movement) — re-classify and
-                # re-dispatch, like the local fallback above.  A second
-                # miss means the chunks are genuinely gone everywhere:
-                # surface that typed (chunk ids + tier + cause), not as a
-                # bare KeyError the caller cannot classify.
-                remote_fallback = True
+            total = 0
+            remote_future: Optional[Future] = None
+            t_remote = 0.0
+            local_fallback = False
+            if remote_items:
+                remote_future = _get_fetch_pool().submit(
+                    self._remote_read, remote_items, stats=stats
+                )
+                t_remote = time.perf_counter()
+            if local_items:
                 try:
-                    total += self.read_batch_into(
-                        remote_items, parallel=parallel,
+                    total += self._local_read(
+                        local_items, parallel=parallel,
                         coalesce_gap=coalesce_gap, stats=stats,
-                        promote=promote,
                     )
-                except KeyError as exc2:
-                    raise TierReadError(
-                        [r.digest for r, _ in remote_items], "remote", exc2
-                    ) from exc
-            except TierReadError:
-                # remote link down / retries exhausted: heal chunk by chunk
-                # (warm tiers, then base sources) instead of failing the
-                # whole restore on one dead tier
-                remote_fallback = True
-                for ref, view in remote_items:
-                    self._recover_chunk(ref, view, "remote",
-                                        corrupt=False, stats=stats)
-                total += sum(r.size for r, _ in remote_items)
-            t_remote = time.perf_counter() - t_remote
-            if promote and not remote_fallback:
-                pairs = [
-                    (ref, bytes(view)) for ref, view in remote_items
-                ]
-                # what promotion will actually append (racing promotions of
-                # the same digests may shrink this further; close enough
-                # for per-restore accounting)
-                promoting_bytes = sum(
-                    r.size for r, _ in pairs if r.digest not in self.local
-                )
-                self._track_promotion(
-                    _get_fetch_pool().submit(self._promote_payloads, pairs)
-                )
+                except KeyError:
+                    # a concurrent demote() moved chunks between classification
+                    # and the read — re-classify and re-dispatch the batch
+                    # through the full hierarchy (idempotent: overwrites any
+                    # partial fills; keeps batching, promote and stats honest)
+                    local_fallback = True
+                    total += self.read_batch_into(
+                        local_items, parallel=parallel,
+                        coalesce_gap=coalesce_gap, stats=stats, promote=promote,
+                    )
+                except TierReadError:
+                    # the pack medium kept failing past the retry budget —
+                    # heal chunk by chunk from the other tiers / base sources
+                    local_fallback = True
+                    for ref, view in local_items:
+                        self._recover_chunk(ref, view, "local",
+                                            corrupt=False, stats=stats)
+                    total += sum(r.size for r, _ in local_items)
+            ram_bytes = sum(len(p) for _, _, p in ram_items)
+            if parallel and ram_bytes > _RAM_PARALLEL_BYTES and len(ram_items) > 1:
+                # ctypes.memmove releases the GIL, so fanned-out copies overlap
+                # the page faults fresh destination buffers take on first write
+                # (memoryview slice-assign holds the GIL and cannot)
+                nshards = min(8, len(ram_items))
+                shards = [ram_items[i::nshards] for i in range(nshards)]
+
+                def _copy(shard):
+                    for _, view, payload in shard:
+                        ctypes.memmove(
+                            ctypes.addressof(ctypes.c_char.from_buffer(view)),
+                            payload, len(payload),
+                        )
+
+                list(_get_io_pool().map(_copy, shards))
+            else:
+                for _, view, payload in ram_items:
+                    view[:] = payload
+            total += ram_bytes
+            promoting_bytes = 0
+            remote_fallback = False
+            if remote_future is not None:
+                try:
+                    total += remote_future.result()
+                except KeyError as exc:
+                    # the remote index changed between classification and the
+                    # read (e.g. a racing movement) — re-classify and
+                    # re-dispatch, like the local fallback above.  A second
+                    # miss means the chunks are genuinely gone everywhere:
+                    # surface that typed (chunk ids + tier + cause), not as a
+                    # bare KeyError the caller cannot classify.
+                    remote_fallback = True
+                    try:
+                        total += self.read_batch_into(
+                            remote_items, parallel=parallel,
+                            coalesce_gap=coalesce_gap, stats=stats,
+                            promote=promote,
+                        )
+                    except KeyError as exc2:
+                        raise TierReadError(
+                            [r.digest for r, _ in remote_items], "remote", exc2
+                        ) from exc
+                except TierReadError:
+                    # remote link down / retries exhausted: heal chunk by chunk
+                    # (warm tiers, then base sources) instead of failing the
+                    # whole restore on one dead tier
+                    remote_fallback = True
+                    for ref, view in remote_items:
+                        self._recover_chunk(ref, view, "remote",
+                                            corrupt=False, stats=stats)
+                    total += sum(r.size for r, _ in remote_items)
+                t_remote = time.perf_counter() - t_remote
+                if promote and not remote_fallback:
+                    pairs = [
+                        (ref, bytes(view)) for ref, view in remote_items
+                    ]
+                    # what promotion will actually append (racing promotions of
+                    # the same digests may shrink this further; close enough
+                    # for per-restore accounting)
+                    promoting_bytes = sum(
+                        r.size for r, _ in pairs if r.digest not in self.local
+                    )
+                    self._track_promotion(
+                        _get_fetch_pool().submit(self._promote_payloads, pairs)
+                    )
         if self.spec.verify_reads:
             # verify once per primary destination, after every stream has
             # landed and before dup copies fan the payloads out.  Fallback
@@ -1344,7 +1348,8 @@ class TieredChunkStore:
                 checks += [(r, v, "local") for r, v in local_items]
             if not remote_fallback:
                 checks += [(r, v, "remote") for r, v in remote_items]
-            self._verify_views(checks, stats=stats)
+            with span("restore.verify", bytes=sum(len(v) for _, v, _ in checks)):
+                self._verify_views(checks, stats=stats)
         for digest, view in dup:
             view[:] = primary[digest]
         if stats is not None:

@@ -28,7 +28,7 @@ from typing import Any, Callable, Dict, Optional, Protocol, Tuple, runtime_check
 
 import numpy as np
 
-from repro.core.metrics import ColdStartMetrics
+from repro.core.metrics import ColdStartMetrics, RequestTrace
 from repro.core.planner import ColdStartPrediction, SnapshotSizes, StorageModel, predict
 
 
@@ -190,6 +190,8 @@ class InvocationResult:
     #: the request completed, but recovery work happened on its path
     #: (tier-read retries, chunk repair, or a worker failover re-dispatch)
     fault_recovered: bool = False
+    #: the request's spans (``repro.request`` and everything under it)
+    trace: Optional[RequestTrace] = None
 
 
 @runtime_checkable

@@ -15,6 +15,7 @@ one-release deprecation window (see DESIGN.md migration notes).
 
 from __future__ import annotations
 
+import itertools
 import os
 import threading
 import time
@@ -27,6 +28,7 @@ import numpy as np
 
 from repro.core import AccessLog, ColdStartMetrics, RestoredInstance, ZygoteRegistry
 from repro.core.planner import PAPER_C220G5, StorageModel, predict_demand_paged
+from repro.core.metrics import RequestTrace, span
 from repro.core.tiers import PrefetchStats, TierSpec
 from repro.core.restore import MaterializedArray
 from repro.core.snapshot import flatten_pytree, resolve
@@ -108,6 +110,7 @@ class Worker:
         # Eq. 1 resolution cache for Strategy.AUTO: fn → (strategy, predictions)
         self._auto: Dict[str, Any] = {}
         self._lock = threading.RLock()
+        self._request_ids = itertools.count()
 
     # -- bootstrap (cluster-manager replication step) -------------------------
 
@@ -336,16 +339,17 @@ class Worker:
         rows2d = ma.patch.rows_2d()
         if rows2d.shape[0] == 0:
             return None  # nothing to patch (shouldn't happen: plan skips)
-        diff2d = jnp.asarray(rows2d.view(np.dtype(meta.dtype)))
-        flat = base_dev.reshape(-1)
-        if n * c != total:  # partial tail chunk: pad base, slice after
-            flat = jnp.pad(flat, (0, n * c - total))
-        # the Pallas kernel on the chip; its jnp oracle elsewhere
-        out = patch_apply_op(
-            flat.reshape(n, c), diff2d, jnp.asarray(ma.patch.sel),
-            mode="replace", use_kernel=jax.default_backend() == "tpu",
-        )
-        out = out.reshape(-1)[:total].reshape(meta.shape)
+        with span("exec.patch", leaf=path, bytes=rows2d.nbytes):
+            diff2d = jnp.asarray(rows2d.view(np.dtype(meta.dtype)))
+            flat = base_dev.reshape(-1)
+            if n * c != total:  # partial tail chunk: pad base, slice after
+                flat = jnp.pad(flat, (0, n * c - total))
+            # the Pallas kernel on the chip; its jnp oracle elsewhere
+            out = patch_apply_op(
+                flat.reshape(n, c), diff2d, jnp.asarray(ma.patch.sel),
+                mode="replace", use_kernel=jax.default_backend() == "tpu",
+            )
+            out = out.reshape(-1)[:total].reshape(meta.shape)
         ma._dev = out
         return out
 
@@ -368,7 +372,8 @@ class Worker:
         into the log here — row-granular where the serving contract is
         row-granular, full otherwise.  Host-path touches are logged by the
         MaterializedArrays themselves (``attach_access_log``)."""
-        template = self.models[spec.family].param_shapes()
+        with span("exec.shapes"):
+            template = self.models[spec.family].param_shapes()
         rows = dict(spec.touched_rows)
         for k, v in (request_rows or {}).items():
             rows[k] = np.union1d(np.asarray(rows.get(k, []), np.int64), v)
@@ -402,7 +407,18 @@ class Worker:
 
     def invoke(self, request: InvocationRequest) -> InvocationResult:
         """Typed request path: warm-pool lookup, cold start (with AUTO
-        resolved through the planner), execution, pool re-admission."""
+        resolved through the planner), execution, pool re-admission.
+
+        The call runs inside its own :class:`RequestTrace` (id
+        ``"<worker_id>:<n>"``) under a ``repro.request`` span; the result
+        carries the trace, and ``boot_s``/``exec_s`` are the lengths of its
+        ``repro.boot``/``repro.exec`` spans."""
+        trace = RequestTrace(f"{self.worker_id}:{next(self._request_ids)}")
+        with trace, span("request", fn=request.function):
+            return self._invoke(request, trace)
+
+    def _invoke(self, request: InvocationRequest,
+                trace: RequestTrace) -> InvocationResult:
         fn = request.function
         opts = request.options
         if self.faults is not None:
@@ -427,39 +443,44 @@ class Worker:
         t0 = time.perf_counter()
         inst = None if opts.force_cold else self.pool.get(fn)
         cold = inst is None
+        boot = 0.0
         if cold:
-            self.pool.drop(fn)
-            loaders = self._loaders(spec)
-            inst = self.registry.cold_start(
-                fn, strategy.value,
-                residual_init=lambda ds: {**ds, "kv_ready": True},
-                engine=opts.engine,
-                promote=opts.promote,
-                demand_paged=demand_paged,
-                **loaders,
-            )
-        boot = time.perf_counter() - t0
+            with span("boot") as boot_span:
+                with span("pool.drop"):
+                    self.pool.drop(fn)
+                loaders = self._loaders(spec)
+                inst = self.registry.cold_start(
+                    fn, strategy.value,
+                    residual_init=lambda ds: {**ds, "kv_ready": True},
+                    engine=opts.engine,
+                    promote=opts.promote,
+                    demand_paged=demand_paged,
+                    **loaders,
+                )
+            boot = boot_span.seconds
+            trace.metrics = inst.metrics
 
-        te = time.perf_counter()
-        record_log = AccessLog() if opts.record else None
-        if record_log is not None:
-            inst.attach_access_log(record_log)
-        req_rows = {}
-        if "embed/table" in spec.touched_rows or "embed/table" in spec.variant \
-                or (spec.delta is not None and "embed/table" in spec.delta):
-            req_rows["embed/table"] = np.unique(np.asarray(request.tokens))
-        params = self._params_for(spec, inst, req_rows, record_log=record_log)
-        logits = self._fwd[spec.family](params, jnp.asarray(request.tokens))
-        logits.block_until_ready()
-        if spec.exec_sleep_s > 0.0:
-            # emulated handler I/O wait (FaaS handlers are mostly I/O
-            # bound): a GIL-releasing sleep, so concurrent slots overlap
-            # like real downstream calls would — the load benches use it
-            # to keep service time parallelizable on small hosts
-            time.sleep(spec.exec_sleep_s)
-        exec_s = time.perf_counter() - te
-        if inst.metrics is not None:
-            inst.metrics.t_exec = exec_s
+        with span("exec") as exec_span:
+            record_log = AccessLog() if opts.record else None
+            if record_log is not None:
+                inst.attach_access_log(record_log)
+            req_rows = {}
+            if "embed/table" in spec.touched_rows or "embed/table" in spec.variant \
+                    or (spec.delta is not None and "embed/table" in spec.delta):
+                req_rows["embed/table"] = np.unique(np.asarray(request.tokens))
+            with span("exec.params"):
+                params = self._params_for(spec, inst, req_rows,
+                                          record_log=record_log)
+            with span("exec.forward"):
+                logits = self._fwd[spec.family](params, jnp.asarray(request.tokens))
+                logits.block_until_ready()
+            if spec.exec_sleep_s > 0.0:
+                # emulated handler I/O wait (FaaS handlers are mostly I/O
+                # bound): a GIL-releasing sleep, so concurrent slots overlap
+                # like real downstream calls would — the load benches use it
+                # to keep service time parallelizable on small hosts
+                time.sleep(spec.exec_sleep_s)
+        exec_s = exec_span.seconds
         if cold and inst.metrics is not None and inst.metrics.demand_paged:
             # recorded chunks still pending were prefetched for nothing
             inst.finalize_demand_paging()
@@ -470,26 +491,30 @@ class Worker:
             inst.attach_access_log(None)
             self.registry.record_access(fn, record_log)
 
-        # charge host buffers AND cached patched device copies (ma._dev) to
-        # the pool budget — a warm patchable instance pins a full-size
-        # accelerator copy, so residency must reflect it (Fig. 7's trade)
-        nbytes = sum(
-            a.meta.nbytes * (2 if a._dev is not None else 1)
-            for a in inst.arrays.values()
-        )
-        pooled = self.pool.put(fn, inst, nbytes,
-                               cost=self.predicted_cost(fn, strategy))
+        with span("pool.put"):
+            # charge host buffers AND cached patched device copies (ma._dev)
+            # to the pool budget — a warm patchable instance pins a
+            # full-size accelerator copy, so residency must reflect it
+            # (Fig. 7's trade)
+            nbytes = sum(
+                a.meta.nbytes * (2 if a._dev is not None else 1)
+                for a in inst.arrays.values()
+            )
+            pooled = self.pool.put(fn, inst, nbytes,
+                                   cost=self.predicted_cost(fn, strategy))
+            output = np.asarray(logits[:, -1, :8])
         m = inst.metrics if cold else None
         return InvocationResult(
             function=fn, cold=cold, requested=Strategy.coerce(opts.strategy),
             strategy=strategy,
-            latency_s=time.perf_counter() - t0, boot_s=boot if cold else 0.0,
+            latency_s=time.perf_counter() - t0, boot_s=boot,
             exec_s=exec_s, pooled=pooled, worker_id=self.worker_id,
             metrics=m,
-            output=np.asarray(logits[:, -1, :8]),
+            output=output,
             fault_recovered=bool(
                 m is not None and (m.read_retries or m.repaired_chunks)
             ),
+            trace=trace,
         )
 
     def _loaders(self, spec: FunctionSpec):
