@@ -51,6 +51,9 @@ class ColdStartMetrics:
     # however many chunks reference it); equals eager_bytes when the eager
     # set shares nothing with itself
     eager_unique_bytes: int = 0
+    # eager bytes read into destinations recycled from an earlier restore
+    # (pages already mapped: no first-touch faults)
+    recycled_bytes: int = 0
     # C: residual init
     t_init: float = 0.0
     # D: execution-time restoration overhead

@@ -21,6 +21,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from .arena import DestArena
 from .chunkstore import DEFAULT_CHUNK_BYTES, ChunkRef, ChunkStore
 from .metrics import ColdStartMetrics, span
 from .planner import SnapshotSizes, StorageModel
@@ -114,6 +115,9 @@ class ZygoteRegistry:
         self._base_index: Optional[Dict[str, Tuple[str, Any, int]]] = None  # guarded-by: _base_index_lock
         self._base_index_lock = threading.Lock()
         self.store.add_fallback_source(self._base_chunk_payload)
+        # patch rows buffers recycled across this worker's cold starts; free
+        # blocks are capped at the largest restore any plan asks for
+        self.arena = DestArena()
 
     # -- bootstrap ----------------------------------------------------------
 
@@ -562,6 +566,7 @@ class ZygoteRegistry:
         else:
             raise ValueError(f"no restore plan for strategy {strategy!r}")
         rec.plans[key] = plan
+        self.arena.fit(plan.rows_bytes)
         return plan
 
     def cold_start(
@@ -604,6 +609,7 @@ class ZygoteRegistry:
             return execute_restore_plan(
                 plan, self.store, pool if strategy != "reap" else None,
                 residual_init=residual_init, promote=promote,
+                arena=self.arena,
             )
         if strategy == "snapfaas":
             if rec.ws is None:

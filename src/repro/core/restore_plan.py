@@ -31,6 +31,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
+from .arena import DestArena
 from .chunkstore import ChunkRef, ChunkStore
 from .metrics import ColdStartMetrics, span
 from .restore import ArrayPatch, BasePool, MaterializedArray, RestoredInstance
@@ -57,6 +58,11 @@ class PlanArray:
     patch_rows: int = 0                                # rows in the buffer
     patch_row_of: Optional[Dict[int, int]] = None
     patch_eager: Tuple[Tuple[int, ChunkRef], ...] = () # (row offset, ref)
+    patch_eager_bytes: int = 0                         # sum of their sizes
+    # (offset, length) of the rows bytes the reads leave alone: the shared
+    # zero row and the tail of a partial last chunk's row — cleared when
+    # the rows buffer is a recycled one
+    patch_clear: Tuple[Tuple[int, int], ...] = ()
     # demand-paged plans: store-pending chunk indices the recording covers
     # (prefetched in the background; materialized on first access)
     recorded: Optional[frozenset] = None
@@ -79,6 +85,8 @@ class RestorePlan:
     #: referencing one digest are read once and replicated by memcpy)
     unique_eager_bytes: int = 0
     shared_bytes: int = 0
+    #: bytes of every patch rows buffer one restore allocates
+    rows_bytes: int = 0
     # tier placement of the eager set when built against a tiered store:
     # {tier name: bytes} plus the store's residency epoch at build time —
     # the registry rebuilds the plan when promotion/demotion moved chunks
@@ -160,6 +168,7 @@ def build_restore_plan(
         patch_eager: List[Tuple[int, ChunkRef]] = []
         recorded: Set[int] = set()
         row_of: Dict[int, int] = {}
+        clear: List[Tuple[int, int]] = []
         sel = (
             np.full(len(ra.sources), -1, dtype=np.int32) if patchable else None
         )
@@ -190,12 +199,15 @@ def build_restore_plan(
                 if ref.zero:
                     if zero_row is None:
                         zero_row = n_rows
+                        clear.append((n_rows * cb, cb))
                         n_rows += 1
                     sel[idx] = zero_row
                 else:
                     row_of[idx] = n_rows
                     sel[idx] = n_rows
                     patch_eager.append((n_rows * cb, ref))
+                    if ref.size < cb:
+                        clear.append((n_rows * cb + ref.size, cb - ref.size))
                     pending.append((idx, None, "rows"))
                     n_rows += 1
                 continue
@@ -212,8 +224,8 @@ def build_restore_plan(
             else:
                 pending.append((idx, ref, "store"))
 
-        eager_bytes += sum(r.size for _, r in eager)
-        eager_bytes += sum(r.size for _, r in patch_eager)
+        patch_eager_bytes = sum(r.size for _, r in patch_eager)
+        eager_bytes += sum(r.size for _, r in eager) + patch_eager_bytes
         eager_chunks += len(eager) + len(patch_eager)
         arrays.append(PlanArray(
             path=path, meta=meta, shared=False,
@@ -222,6 +234,8 @@ def build_restore_plan(
             patch_rows=n_rows,
             patch_row_of=row_of if patchable else None,
             patch_eager=tuple(patch_eager),
+            patch_eager_bytes=patch_eager_bytes,
+            patch_clear=tuple(clear),
             recorded=frozenset(recorded) if demand_paged else None,
         ))
 
@@ -232,6 +246,8 @@ def build_restore_plan(
         arrays=arrays, device_state=device_state,
         eager_bytes=eager_bytes, eager_chunks=eager_chunks,
         shared_bytes=shared_bytes,
+        rows_bytes=sum(pa.patch_rows * pa.meta.chunk_bytes
+                       for pa in arrays if pa.patch_sel is not None),
         demand_paged=demand_paged,
         prefetch_refs=tuple(prefetch_refs),
         prefetch_bytes=prefetch_bytes,
@@ -261,6 +277,7 @@ def execute_restore_plan(
     *,
     residual_init: Optional[Callable[[Dict[str, Any]], Dict[str, Any]]] = None,
     promote: Optional[bool] = None,
+    arena: Optional[DestArena] = None,
 ) -> RestoredInstance:
     """The cold-start hot path: allocate, scatter-read, done.
 
@@ -273,6 +290,11 @@ def execute_restore_plan(
 
     ``promote`` forwards to the tiered store: whether remote-fetched chunks
     are promoted downward (None → the store's configured default).
+
+    ``arena`` serves the patch rows buffers from blocks an earlier restore
+    left mapped (without one, every buffer is a fresh ``np.zeros``, as on
+    a miss); the bytes the reads leave alone are cleared from the plan's
+    ``patch_clear``.
     """
     m = ColdStartMetrics(strategy=plan.strategy, function=plan.function)
 
@@ -280,6 +302,10 @@ def execute_restore_plan(
     with span("restore.alloc") as s:
         arrays: Dict[Path, MaterializedArray] = {}
         dests: List[Tuple[ChunkRef, memoryview]] = []
+        blocks = iter((arena or DestArena()).acquire([
+            pa.patch_rows * pa.meta.chunk_bytes
+            for pa in plan.arrays if pa.patch_sel is not None
+        ]))
         for pa in plan.arrays:
             if pa.shared:
                 assert pool is not None
@@ -293,7 +319,11 @@ def execute_restore_plan(
                 recorded=pa.recorded,
             )
             if pa.patch_sel is not None:
-                rows = np.zeros(pa.patch_rows * pa.meta.chunk_bytes, dtype=np.uint8)
+                rows, recycled = next(blocks)
+                if recycled:
+                    for off, n in pa.patch_clear:
+                        rows[off : off + n] = 0
+                    m.recycled_bytes += pa.patch_eager_bytes
                 ma.patch = ArrayPatch(
                     sel=pa.patch_sel, rows=rows,
                     row_of=pa.patch_row_of or {}, chunk_bytes=pa.meta.chunk_bytes,

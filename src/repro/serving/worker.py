@@ -20,7 +20,7 @@ import os
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -44,6 +44,18 @@ from repro.serving.api import (
     select_strategy,
 )
 from repro.serving.policy import InstancePool, PoolPolicy
+
+
+def _map_paths(tree: Any, leaf: Callable[[str], Any], prefix: str = "") -> Any:
+    """``tree``'s nested dicts with each leaf replaced by ``leaf(path)``.
+
+    A module function, not a recursive closure: a closure that calls itself
+    holds what it captures (the restored instance) in a reference cycle,
+    which outlives the instance's drop until the garbage collector runs."""
+    if isinstance(tree, dict):
+        return {k: _map_paths(v, leaf, f"{prefix}{k}/") for k, v in tree.items()}
+    return leaf(prefix[:-1])
+
 
 PyTree = Any
 
@@ -380,10 +392,7 @@ class Worker:
 
         pool_dev = self._pool_dev.get(spec.family, {})
 
-        def rec(t, prefix):
-            if isinstance(t, dict):
-                return {k: rec(v, f"{prefix}{k}/") for k, v in t.items()}
-            path = prefix[:-1]
+        def leaf(path):
             ma = inst.arrays[path]
             if ma.state == "shared" and not ma.written and path in pool_dev:
                 if record_log is not None:
@@ -403,7 +412,7 @@ class Worker:
                 arr = inst.value(path)
             return jnp.asarray(arr)
 
-        return rec(template, "")
+        return _map_paths(template, leaf)
 
     def invoke(self, request: InvocationRequest) -> InvocationResult:
         """Typed request path: warm-pool lookup, cold start (with AUTO

@@ -428,6 +428,35 @@ class TestBitFlipRepair:
         assert store.tier_stats()["health"]["repaired_chunks"] > 0
 
 
+    def test_bitflips_read_into_recycled_rows_are_repaired(self, tmp_path):
+        """A recycled rows buffer holds the previous instance's bytes (here
+        scribbled over); every chunk flipped in flight into it is caught and
+        repaired in place, so verification checks what the read wrote, not
+        what the buffer held."""
+        reg = ZygoteRegistry(
+            str(tmp_path / "reg"), chunk_bytes=CHUNK,
+            tiers=TierSpec(ram_bytes=0, retry=FAST_RETRY, **FAST_REMOTE,
+                           faults=FaultInjector(FaultMatrix(
+                               seed=4, bit_flip=1.0, tiers=("local",)))),
+        )
+        rng = np.random.default_rng(8)
+        base = {f"layer{i}": {"w": rng.standard_normal((100, 30)).astype(np.float32)}
+                for i in range(2)}
+        reg.register_runtime("fam", base)
+        variant = {k: {"w": v["w"] + 0.5} for k, v in base.items()}
+        reg.register_function("fn", "fam", variant)
+        first = reg.cold_start("fn", "snapfaas-")
+        for ma in first.arrays.values():
+            ma.patch.rows[:] = 0x5A
+        del first, ma
+        inst = reg.cold_start("fn", "snapfaas-")
+        m = inst.metrics
+        assert m.recycled_bytes == m.eager_bytes > 0
+        assert m.repaired_chunks == m.eager_chunks > 0
+        for p, a in flatten_pytree(variant).items():
+            np.testing.assert_array_equal(inst.value(p), a, err_msg=p)
+
+
 # ---------------------------------------------------------- hedged fetches
 
 class TestHedgedFetch:

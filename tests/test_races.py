@@ -409,3 +409,54 @@ class TestTierCounterExactness:
             f"lost counter updates: {stats['prefetched_bytes']} != {total}"
         )
         assert all(store.tier_of(r.digest) == "ram" for r in refs)
+
+
+class TestArenaRace:
+    def test_no_block_is_handed_to_two_live_instances(self, tmp_path):
+        """More threads than cores cold-start one function at a short switch
+        interval, check the restored bytes, stamp every rows buffer with
+        their own byte and read the stamp back: a block handed to two live
+        instances at once would show another thread's byte."""
+        import os
+        import sys
+        import time
+
+        reg, variant = _registry(tmp_path)
+        want = variant["layer2"]["w"]
+        n = (os.cpu_count() or 4) + 4
+        errors, counts = [], [0] * n
+        stop = time.monotonic() + 2.0
+
+        def work(i):
+            try:
+                while time.monotonic() < stop:
+                    inst = reg.cold_start("fn", "snapfaas")
+                    np.testing.assert_array_equal(inst.value("layer2/w"), want)
+                    rows = [ma.patch.rows for ma in inst.arrays.values()
+                            if ma.patch is not None]
+                    for r in rows:
+                        r[:] = i
+                    time.sleep(0)
+                    if not all(np.all(r == i) for r in rows):
+                        errors.append(f"thread {i}: rows overwritten")
+                    counts[i] += 1
+                    del inst, rows
+            except Exception as e:  # broad-ok: surfaced by the assert below
+                errors.append(e)
+
+        threads = [threading.Thread(target=work, args=(i,)) for i in range(n)]
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+        finally:
+            sys.setswitchinterval(old)
+        assert not any(t.is_alive() for t in threads)
+        assert not errors, errors[:3]
+        assert all(counts)
+        stats = reg.arena.stats()
+        assert stats["hits"] > 0
+        assert stats["free_bytes"] <= stats["cap"]

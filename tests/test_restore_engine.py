@@ -1,6 +1,8 @@
 """Planned restore engine: equivalence with the seed path, scatter-read
 correctness, coalescing properties, and the on-device patch path."""
 
+import gc
+
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -256,6 +258,148 @@ class TestCoalesceProperties:
         for i, b in bufs.items():
             expect = b"\x00" * refs[i].size if refs[i].zero else payloads[i]
             assert bytes(b) == expect
+
+
+# ------------------------------------------------------------ recycled rows
+
+def _patchable_registry(tmp_path, strategy):
+    """Every dirty array patchable: one whose last chunk is partial, one
+    with an all-zero diff chunk (the plan's zero row), one untouched."""
+    reg = ZygoteRegistry(str(tmp_path / "reg"), chunk_bytes=CHUNK)
+    rng = np.random.default_rng(3)
+    base = {
+        "tail": {"w": rng.standard_normal((100, 30)).astype(np.float32)},
+        "zero": {"w": rng.standard_normal((64, 32)).astype(np.float32)},
+        "same": {"w": rng.standard_normal((64, 32)).astype(np.float32)},
+    }
+    reg.register_runtime("fam", base)
+    variant = {k: {"w": v["w"].copy()} for k, v in base.items()}
+    variant["tail"]["w"] += 0.5              # 12,000 B: chunks 4096, 4096, 3808
+    variant["zero"]["w"][:32] = 0.0          # chunk 0 all zero
+    variant["zero"]["w"][32:] += 1.0
+    reg.register_function("fn", "fam", variant)
+    if strategy == "snapfaas":
+        log = AccessLog()
+        for p in ("tail/w", "zero/w"):
+            log.touch(p)
+        reg.generate_working_set("fn", log)
+    return reg, flatten_pytree(variant)
+
+
+def _rows(inst):
+    return {p: ma.patch.rows for p, ma in inst.arrays.items() if ma.patch is not None}
+
+
+class TestRecycledRows:
+    @pytest.mark.parametrize("holder", [
+        "none", "instance", "reshape", "dtype", "memoryview", "jnp"])
+    @pytest.mark.parametrize("strategy", ["snapfaas", "snapfaas-"])
+    def test_restore_into_fresh_or_recycled_rows(self, tmp_path, strategy, holder):
+        """A dropped instance's rows come back to the next restore only when
+        nothing views them; either way the restore is byte-equal to one into
+        fresh buffers, zero row and partial tail included, the counter says
+        which it was, and free blocks stay under the arena's cap."""
+        import jax.numpy as jnp
+        from repro.core import execute_restore_plan
+
+        reg, flat = _patchable_registry(tmp_path, strategy)
+        arena = reg.arena
+        first = reg.cold_start("fn", strategy)
+        plan = reg.functions["fn"].plans[strategy]
+        assert first.metrics.recycled_bytes == 0
+        assert set(_rows(first)) == {"tail/w", "zero/w"}
+        assert plan.eager_bytes == sum(
+            pa.patch_eager_bytes for pa in plan.arrays if pa.patch_sel is not None)
+        assert plan.rows_bytes == arena.stats()["cap"]
+        make = {
+            "none": lambda r: None, "instance": lambda r: first,
+            "reshape": lambda r: r.reshape(-1, CHUNK),
+            "dtype": lambda r: r.view(np.float32),
+            "memoryview": memoryview, "jnp": jnp.asarray,
+        }[holder]
+        views, viewed = [], set()
+        for path, rows in _rows(first).items():
+            rows[:] = 0xAB                   # stale bytes a recycled block keeps
+            views.append(make(rows))
+            # a jnp array views the block only where the CPU backend aliased it
+            if holder != "none" and (holder != "jnp" or
+                                     views[-1].unsafe_buffer_pointer() == rows.ctypes.data):
+                viewed.add(path)
+        del rows
+        if holder != "instance":
+            del first
+        gc.collect()  # where JAX copied a source, it lets go at a collector pass
+
+        second = reg.cold_start("fn", strategy)
+        assert second.metrics.recycled_bytes == sum(
+            pa.patch_eager_bytes for pa in plan.arrays
+            if pa.patch_sel is not None and pa.path not in viewed)
+        assert arena.stats()["free_bytes"] <= arena.stats()["cap"]
+        # whatever still views a block reads the bytes it had
+        held = (list(_rows(first).values()) if holder == "instance"
+                else [v for v in views if v is not None])
+        for v in held:
+            assert np.all(np.asarray(v).reshape(-1).view(np.uint8) == 0xAB)
+        del held
+        fresh = execute_restore_plan(plan, reg.store, reg.pools["fam"])
+        for path, rows in _rows(second).items():
+            want = fresh.arrays[path].patch
+            assert bytes(rows) == bytes(want.rows), path
+            np.testing.assert_array_equal(second.arrays[path].patch.sel, want.sel)
+        for path, expected in flat.items():
+            np.testing.assert_array_equal(second.value(path), expected, err_msg=path)
+
+        # free blocks past one restore's worth are trimmed, oldest first
+        third = reg.cold_start("fn", strategy)
+        del second, third, views, fresh
+        if holder == "instance":
+            del first
+        stats = arena.stats()
+        assert 0 < stats["free_bytes"] <= stats["cap"]
+        assert stats["trims"] > 0
+
+
+    def test_worker_recycles_rows_after_pool_drop(self, tmp_path):
+        """Through the serving path: nothing keeps a dropped instance alive
+        (no reference cycle waits for the collector), so the next cold
+        start of the function reads into its rows."""
+        import weakref
+
+        import jax
+        from repro.models import build_model
+        from repro.models.config import ModelConfig
+        from repro.serving import ColdStartOptions, InvocationRequest, Strategy
+        from repro.serving.worker import FunctionSpec, Worker
+
+        cfg = ModelConfig(
+            name="t", family="dense", num_layers=2, d_model=64, num_heads=2,
+            num_kv_heads=2, d_ff=128, vocab_size=256, tie_embeddings=True,
+            dtype="float32",
+        )
+        model = build_model(cfg)
+        worker = Worker(str(tmp_path / "w"), chunk_bytes=4096)
+        base_params = model.init(0)
+        worker.register_runtime("t", model, base_params)
+        variant = {k: np.array(v) + (0.01 if k.endswith("wq") else 0.0)
+                   for k, v in flatten_pytree(jax.tree.map(np.asarray, base_params)).items()}
+        worker.register_function(FunctionSpec(name="fn", family="t", variant=variant))
+        req = InvocationRequest(
+            function="fn", tokens=np.zeros((1, 8), np.int32),
+            options=ColdStartOptions(strategy=Strategy.SNAPFAAS, force_cold=True))
+
+        first = worker.invoke(req)
+        assert first.metrics.eager_bytes > 0 and first.metrics.recycled_bytes == 0
+        gc.disable()
+        try:
+            inst = weakref.ref(worker.pool.get("fn"))
+            worker.pool.drop("fn")
+            assert inst() is None
+        finally:
+            gc.enable()
+        gc.collect()  # where JAX copied a transfer source, it lets go at a collector pass
+        second = worker.invoke(req)
+        assert second.metrics.recycled_bytes == second.metrics.eager_bytes > 0
+        np.testing.assert_array_equal(second.output, first.output)
 
 
 # ------------------------------------------------------------- device patch
