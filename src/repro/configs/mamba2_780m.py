@@ -1,5 +1,10 @@
-"""Mamba2-780m [arXiv:2405.21060; unverified]: attention-free SSD
-(state-space duality), ssm_state=128, 48 layers."""
+"""Mamba2-780m (state-spaces/mamba2-780m, config.json on the Hugging Face
+hub; arXiv:2405.21060): attention-free SSD (state-space duality),
+ssm_state=128, 48 layers, RMSNorm eps 1e-5, the residual stream carried
+in float32, the head tied to the embedding.  The published table has
+50,288 rows (50,277 tokens padded to a multiple of 16); 50,280 here, and
+the benchmark's published configuration sets 50,288 through its
+``program`` override."""
 
 from repro.models.config import ModelConfig
 
@@ -19,4 +24,6 @@ CONFIG = ModelConfig(
     ssm_head_dim=64,
     ssm_chunk=256,
     tie_embeddings=True,
+    residual_in_fp32=True,
+    rms_norm_eps=1e-5,
 )

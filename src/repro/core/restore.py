@@ -252,9 +252,11 @@ class MaterializedArray:
         the buffer WITHOUT materializing the remaining pending chunks.
 
         Rows outside the working set fault in correctly here — they are just
-        synchronous disk reads charged to execution time (term D). Rows never
-        requested keep base-snapshot content in the buffer; by construction
-        (the serving layer ensures every gathered row) they are never read."""
+        synchronous disk reads charged to execution time (term D). Chunks of
+        rows never requested stay pending, their bytes not yet the
+        function's; by construction (the serving layer ensures every
+        gathered row, and a table the head reads is never row-granular)
+        they are never read."""
         if self.access_log is not None:
             self.access_log.touch_rows(self.path, rows)
         if self.state == _SHARED or not self._pending:

@@ -87,7 +87,7 @@ class Model:
             positions=jnp.arange(frames.shape[1]), causal=False,
             remat=self.remat,
         )
-        return apply_norm(h, params["enc"]["final_norm"], cfg.norm)
+        return apply_norm(h, params["enc"]["final_norm"], cfg)
 
     # -- full-sequence forward ----------------------------------------------
 
@@ -107,7 +107,7 @@ class Model:
                 positions=jnp.arange(tokens.shape[1]), cross_states=enc,
                 remat=self.remat,
             )
-            return apply_norm(h, params["final_norm"], cfg.norm)
+            return apply_norm(h, params["final_norm"], cfg)
 
         h = self._embed(params, tokens)
         prefix_len = 0
@@ -121,7 +121,7 @@ class Model:
             remat_group=self.remat_group,
         )
         self._last_aux = aux
-        h = apply_norm(h, params["final_norm"], self.cfg.norm)
+        h = apply_norm(h, params["final_norm"], self.cfg)
         if prefix_len:
             h = h[:, prefix_len:, :]
         return h
@@ -197,7 +197,7 @@ class Model:
                 positions=jnp.arange(tokens.shape[1]), cross_states=enc,
                 make_cache=True, cache_len=cache_len, remat=self.remat,
             )
-            h = apply_norm(h, params["final_norm"], cfg.norm)
+            h = apply_norm(h, params["final_norm"], cfg)
             return self._logits_head(params, h[:, -1:, :]), caches
 
         h = self._embed(params, tokens)
@@ -210,7 +210,7 @@ class Model:
             positions=jnp.arange(h.shape[1]), prefix_len=prefix_len,
             make_cache=True, cache_len=cache_len, remat=self.remat,
         )
-        h = apply_norm(h, params["final_norm"], cfg.norm)
+        h = apply_norm(h, params["final_norm"], cfg)
         return self._logits_head(params, h[:, -1:, :]), caches
 
     # -- decode -----------------------------------------------------------------
@@ -240,7 +240,7 @@ class Model:
                 cfg, self.plan.kinds, params["blocks"], h,
                 positions=jnp.arange(1), cache=cache, decode=True, pos=pos,
             )
-        h = apply_norm(h, params["final_norm"], cfg.norm)
+        h = apply_norm(h, params["final_norm"], cfg)
         logits = self._logits_head(params, h)
         return logits[:, 0, :], new_cache
 

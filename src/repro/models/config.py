@@ -21,6 +21,7 @@ class ModelConfig:
 
     # norms / activations
     norm: str = "rmsnorm"          # rmsnorm | layernorm
+    rms_norm_eps: float = 1e-6     # every RMSNorm (mamba2: 1e-5); LayerNorm keeps 1e-5
     hidden_act: str = "silu"       # silu | gelu
     mlp_gated: bool = True         # SwiGLU / GeGLU
     embed_scale: bool = False      # gemma: scale embeddings by sqrt(d_model)
@@ -54,6 +55,10 @@ class ModelConfig:
 
     # embeddings / heads
     tie_embeddings: bool = True
+
+    # carry the residual stream between layers in float32 (mamba_ssm's
+    # ``residual_in_fp32``); each layer still computes in ``dtype``
+    residual_in_fp32: bool = False
 
     # encoder-decoder (whisper)
     is_encoder_decoder: bool = False

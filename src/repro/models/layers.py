@@ -26,9 +26,10 @@ def layernorm(x: jax.Array, scale: jax.Array, bias: jax.Array, eps: float = 1e-5
     return (y * scale.astype(jnp.float32) + bias.astype(jnp.float32)).astype(dtype)
 
 
-def apply_norm(x: jax.Array, p, kind: str) -> jax.Array:
-    if kind == "rmsnorm":
-        return rmsnorm(x, p["scale"])
+def apply_norm(x: jax.Array, p, cfg) -> jax.Array:
+    """The config's norm (``cfg.norm``, RMSNorm at ``cfg.rms_norm_eps``)."""
+    if cfg.norm == "rmsnorm":
+        return rmsnorm(x, p["scale"], cfg.rms_norm_eps)
     return layernorm(x, p["scale"], p["bias"])
 
 

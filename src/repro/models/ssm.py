@@ -189,6 +189,6 @@ def mamba_mixer(
         )
 
     y = y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)
-    y = rmsnorm(y, params["gate_norm"])
+    y = rmsnorm(y, params["gate_norm"], cfg.rms_norm_eps)
     out = jnp.einsum("ble,ed->bld", y, shard(params["w_out"], "inner", None))
     return out, new_cache

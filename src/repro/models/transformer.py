@@ -185,7 +185,7 @@ def apply_layer(
     new_cache: Dict[str, Any] = {}
     # the residual stream h may be f32 (carry precision); compute in cfg dtype
     cdt = jnp.dtype(cfg.dtype) if h.dtype == jnp.float32 else h.dtype
-    x = apply_norm(h, p["ln1"], cfg.norm).astype(cdt)
+    x = apply_norm(h, p["ln1"], cfg).astype(cdt)
 
     if kind.mixer == "attn":
         window = cfg.sliding_window if kind.is_local else 0
@@ -236,7 +236,7 @@ def apply_layer(
 
     # cross-attention (whisper decoder)
     if cross_states is not None:
-        xc = apply_norm(h, p["ln_cross"], cfg.norm).astype(cdt)
+        xc = apply_norm(h, p["ln_cross"], cfg).astype(cdt)
         q = jnp.einsum("bld,dhk->blhk", xc, p["cq"])
         if decode:
             assert cache is not None and "ck" in cache
@@ -255,7 +255,7 @@ def apply_layer(
         h = h + jnp.einsum("blhk,hkd->bld", attn, p["co"])
 
     if kind.ffn != "none":
-        x2 = apply_norm(h, p["ln2"], cfg.norm).astype(cdt)
+        x2 = apply_norm(h, p["ln2"], cfg).astype(cdt)
         if kind.ffn == "moe":
             from repro.distrib.act import current_binding
             from .moe import moe_ffn_sharded
@@ -322,9 +322,10 @@ def apply_stack(
 
     # f32 residual stream: the scan carry (= the remat h-stack under
     # training) is stored once in f32 instead of bf16 + an XLA-hoisted f32
-    # copy of the whole stack (measured 3× the bf16 stack otherwise).
+    # copy of the whole stack (measured 3× the bf16 stack otherwise); a
+    # config with ``residual_in_fp32`` carries it so in every mode.
     # Per-layer compute still runs in cfg.dtype (see apply_layer).
-    if remat:
+    if remat or cfg.residual_in_fp32:
         h = h.astype(jnp.float32)
     carry0 = (h, jnp.zeros((), jnp.float32))
 

@@ -374,9 +374,12 @@ class Worker:
 
         Gather-type leaves (embedding tables, expert banks — declared via
         ``touched_rows``) use row-granular demand materialization: only the
-        chunks covering the request's rows fault in; everything else of the
-        leaf keeps base content and is never read. Other touched leaves
-        materialize fully. This is the exec-time half of the WS win.
+        chunks covering the request's rows fault in; every other chunk of
+        the leaf stays pending, its bytes not yet the function's, and is
+        never read. Other touched leaves materialize fully. This is the
+        exec-time half of the WS win. A table tied to the head is read
+        whole by the logits, so it is never row-granular: its pending
+        chunks, changed or shared with the base, all fault in.
 
         ``record_log`` is REAP's record mode: leaves served through the
         device shortcuts (zero-copy pool share, on-device patch) bypass the
@@ -389,6 +392,8 @@ class Worker:
         rows = dict(spec.touched_rows)
         for k, v in (request_rows or {}).items():
             rows[k] = np.union1d(np.asarray(rows.get(k, []), np.int64), v)
+        if self.models[spec.family].cfg.tie_embeddings:
+            rows.pop("embed/table", None)
 
         pool_dev = self._pool_dev.get(spec.family, {})
 
