@@ -27,9 +27,9 @@ import json
 import mmap
 import os
 import threading
-from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import ThreadPoolExecutor, wait as wait_futures
 from dataclasses import dataclass
-from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
+from typing import Callable, Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
@@ -188,6 +188,12 @@ def scan_chunks(
 # ---------------------------------------------------------------------------
 # scatter-read planning
 # ---------------------------------------------------------------------------
+
+#: one chunk a scatter-read run filled: its ref and its destination view
+Landed = Tuple[ChunkRef, memoryview]
+#: called by a scatter-read with the chunks of each run as it lands
+RunHook = Callable[[List[Landed]], None]
+
 
 def coalesce_ranges(
     ranges: Sequence[Tuple[int, int]], gap: int = COALESCE_GAP
@@ -727,6 +733,7 @@ class ChunkStore:
         *,
         parallel: bool = True,
         coalesce_gap: int = COALESCE_GAP,
+        on_run: Optional[RunHook] = None,
     ) -> int:
         """Planned scatter-read: each chunk's payload lands **directly** in
         its destination buffer with zero intermediate copies.
@@ -739,6 +746,13 @@ class ChunkStore:
         scratch view covering each coalescing gap); large runs are split and
         all runs are issued across a small thread pool so I/O overlaps
         between packs and within large packs.
+
+        ``on_run``, when given, is called on the thread that read each run,
+        right after its ``preadv`` returns, with the ``(ref, destination
+        view)`` pairs of the chunks that run filled, while their bytes are
+        still in cache.  It sees each distinct digest once: repeated digests
+        are copied only after every run has landed.  The call returns only
+        after every run has ended, failed runs included.
 
         Returns the number of bytes read from storage (gap bytes included).
         """
@@ -760,53 +774,67 @@ class ChunkStore:
                 continue
             primary[ref.digest] = view
             loc = self._loc_for(ref)
-            by_pack.setdefault(loc.pack, []).append((loc.offset, loc.size, view))
+            by_pack.setdefault(loc.pack, []).append(
+                (loc.offset, loc.size, view, ref))
 
-        # plan: per pack, coalesce into runs of (file_offset, [iovec segments])
-        jobs: List[Tuple[int, int, List[memoryview]]] = []  # (fd, offset, iovecs)
+        # plan: per pack, coalesce into runs of (file_offset, [iovec
+        # segments]), each with the chunks whose whole view it fills
+        jobs: List[Tuple[int, int, List[memoryview], List[Landed]]] = []
         for pack_id, items in by_pack.items():
             fd = self._pack_fd(pack_id)
-            runs = coalesce_ranges([(off, size) for off, size, _ in items],
+            runs = coalesce_ranges([(off, size) for off, size, _, _ in items],
                                    gap=coalesce_gap)
             for start, end, members in runs:
-                segs: List[memoryview] = []
+                segs: List[Tuple[memoryview, Optional[Landed]]] = []
                 pos = start
                 for i in members:
-                    off, size, view = items[i]
+                    off, size, view, ref = items[i]
                     if off > pos:  # coalescing gap → discard into scratch
-                        segs.append(memoryview(bytearray(off - pos)))
+                        segs.append((memoryview(bytearray(off - pos)), None))
                         pos = off
                     if off + size <= pos:
                         continue  # fully inside an already-covered range
+                    whole: Optional[Landed] = (ref, view)
                     if off < pos:  # partial overlap (shouldn't happen: chunks
                         view = view[pos - off:]  # are disjoint, but stay safe)
-                        off = pos
-                    segs.append(view)
+                        off, whole = pos, None
+                    segs.append((view, whole))
                     pos = off + len(view)
                 if pos < end:
-                    segs.append(memoryview(bytearray(end - pos)))
+                    segs.append((memoryview(bytearray(end - pos)), None))
                 # split long runs so threads overlap I/O inside one pack, and
                 # respect IOV_MAX per syscall
                 cur: List[memoryview] = []
+                landed: List[Landed] = []
                 cur_off = start
                 cur_bytes = 0
-                for seg in segs:
+                for seg, whole in segs:
                     cur.append(seg)
+                    if whole is not None:
+                        landed.append(whole)
                     cur_bytes += len(seg)
                     if cur_bytes >= _SPLIT_BYTES or len(cur) >= _IOV_MAX:
-                        jobs.append((fd, cur_off, cur))
+                        jobs.append((fd, cur_off, cur, landed))
                         cur_off += cur_bytes
-                        cur, cur_bytes = [], 0
+                        cur, landed, cur_bytes = [], [], 0
                 if cur:
-                    jobs.append((fd, cur_off, cur))
+                    jobs.append((fd, cur_off, cur, landed))
 
-        total = 0
+        def run(job) -> int:
+            fd, offset, iovecs, landed = job
+            n = _read_segments(fd, offset, iovecs)
+            if on_run is not None and landed:
+                on_run(landed)
+            return n
+
         if parallel and len(jobs) > 1:
-            for n in _get_io_pool().map(lambda j: _read_segments(*j), jobs):
-                total += n
+            # wait for every run before raising, so that no run still writes
+            # into (or reports) a destination once this call has returned
+            futures = [_get_io_pool().submit(run, j) for j in jobs]
+            wait_futures(futures)
+            total = sum(f.result() for f in futures)
         else:
-            for j in jobs:
-                total += _read_segments(*j)
+            total = sum(run(j) for j in jobs)
 
         for digest, view in dup:
             view[:] = primary[digest]

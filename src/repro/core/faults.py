@@ -377,26 +377,28 @@ class FaultInjector:
             self._count(f"{tier}.slow_read")
             time.sleep(m.slow_s)
 
-    def after_read(self, tier: str, items: Sequence) -> None:
-        """Corrupt payloads *in flight* (after the medium read, before the
-        caller sees them) — what digest verification must catch."""
-        if tier not in self.matrix.tiers:
-            return
+    def corruption(self, tier: str, items: Sequence) -> "Corruption":
+        """Draw the in-flight corruption of one read of ``items`` (what digest
+        verification must catch): per chunk a bit flip, per read a partial
+        read.  The draws are made here, in item order, so a seeded run
+        replays them whatever order the read's streams land in;
+        :class:`Corruption` applies them to the landed bytes."""
+        ops: Dict[int, list] = {}
         m = self.matrix
-        if m.bit_flip > 0.0:
-            flips = 0
-            for _ref, view in items:
+        if tier in m.tiers and m.bit_flip > 0.0:
+            for k, (_ref, view) in enumerate(items):
                 if self._p(m.bit_flip) and len(view):
-                    view[self._randint(len(view))] ^= 0x40
-                    flips += 1
-            if flips:
-                self._count(f"{tier}.bit_flip", flips)
-        if m.partial_read > 0.0 and items and self._p(m.partial_read):
-            _ref, view = items[self._randint(len(items))]
-            half = len(view) // 2
-            if half:
-                view[half:] = b"\x00" * (len(view) - half)
-                self._count(f"{tier}.partial_read")
+                    ops.setdefault(k, []).append(self._randint(len(view)))
+        if (tier in m.tiers and m.partial_read > 0.0 and items
+                and self._p(m.partial_read)):
+            k = self._randint(len(items))
+            if len(items[k][1]) // 2:
+                ops.setdefault(k, []).append(None)
+        return Corruption(tier, items, ops, self._count)
+
+    def after_read(self, tier: str, items: Sequence) -> None:
+        """Corrupt payloads *in flight*, after the whole read of ``items``."""
+        self.corruption(tier, items).apply_rest()
 
     def wrap_tier(self, tier) -> "FaultyTier":
         return FaultyTier(tier, self)
@@ -444,14 +446,70 @@ class FaultyTier:
     def has(self, digest: str) -> bool:
         return self._inner.has(digest)
 
-    def read_into(self, items, **kwargs) -> int:
+    def read_into(self, items, on_run=None, **kwargs) -> int:
+        """The inner read, with the matrix's faults.  With ``on_run`` (the
+        caller's per-run hook) each run is corrupted as it lands, before the
+        hook sees it, so a flip injected into a run is hashed as flipped;
+        whatever the inner tier did not hand to the hook is corrupted after
+        the whole read, as without one."""
         self._inj.before_read(self.name, items)
+        corrupt = self._inj.corruption(self.name, items)
+        if on_run is not None:
+            def corrupt_then(landed):
+                corrupt.apply(landed)
+                on_run(landed)
+
+            kwargs["on_run"] = corrupt_then
         n = self._inner.read_into(items, **kwargs)
-        self._inj.after_read(self.name, items)
+        corrupt.apply_rest()
         return n
 
     def __getattr__(self, attr):
         return getattr(self._inner, attr)
+
+
+class Corruption:
+    """The in-flight corruption drawn for one read: per item index, bit
+    positions to flip (``None``: zero the second half, a partial read).
+    :meth:`apply` corrupts chunks as a stream lands them, :meth:`apply_rest`
+    every item not yet corrupted.  Each item is corrupted at most once, and
+    counted on the injector when it is."""
+
+    def __init__(self, tier: str, items: Sequence, ops: Dict[int, list], count):
+        self._tier = tier
+        self._items = items
+        self._ops = ops
+        self._count = count
+        # digest -> its first item, the one a stream lands
+        self._first: Dict[str, int] = {}
+        if ops:
+            for k, (ref, _view) in reversed(list(enumerate(items))):
+                self._first[ref.digest] = k
+
+    def _corrupt(self, view, ops) -> None:
+        for pos in ops:
+            if pos is None:
+                half = len(view) // 2
+                view[half:] = b"\x00" * (len(view) - half)
+                self._count(f"{self._tier}.partial_read")
+            else:
+                view[pos] ^= 0x40
+                self._count(f"{self._tier}.bit_flip")
+
+    def apply(self, landed: Sequence) -> None:
+        """Corrupt the landed ``(ref, view)`` pairs of one stream's run (a
+        repeated digest lands once, as its first item)."""
+        if not self._ops:
+            return
+        for ref, view in landed:
+            ops = self._ops.pop(self._first.get(ref.digest), None)
+            if ops:
+                self._corrupt(view, ops)
+
+    def apply_rest(self) -> None:
+        while self._ops:
+            k, ops = self._ops.popitem()
+            self._corrupt(self._items[k][1], ops)
 
 
 # ---------------------------------------------------------------------------

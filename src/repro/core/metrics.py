@@ -54,6 +54,9 @@ class ColdStartMetrics:
     # eager bytes read into destinations recycled from an earlier restore
     # (pages already mapped: no first-touch faults)
     recycled_bytes: int = 0
+    # eager bytes whose digest was taken inside their read stream, while
+    # still in cache, rather than by the pass after every stream landed
+    inline_verified_bytes: int = 0
     # C: residual init
     t_init: float = 0.0
     # D: execution-time restoration overhead

@@ -391,6 +391,7 @@ def execute_restore_plan(
             m.promoted_bytes = stats.promoted_bytes
             m.read_retries = stats.retries
             m.repaired_chunks = stats.repaired_chunks
+            m.inline_verified_bytes = stats.inline_verified_bytes
         else:
             with span("restore.read", bytes=plan.eager_bytes):
                 store.read_batch_into(dests)

@@ -54,6 +54,7 @@ from .chunkstore import (
     ChunkRef,
     ChunkStore,
     PackWriter,
+    RunHook,
     _get_io_pool,
     chunk_digest,
     digest_many,
@@ -133,6 +134,9 @@ class TierReadStats:
     repaired_chunks: int = 0    # chunks healed from another tier / base
     repaired_bytes: int = 0
     verify_failures: int = 0    # digest mismatches detected
+    # bytes whose digest was taken inside their stream, right after they
+    # landed (copies of a repeated digest ride on its one digest)
+    inline_verified_bytes: int = 0
 
     def add(self, tier: str, chunks: int, nbytes: int) -> None:
         self.tier_chunks[tier] = self.tier_chunks.get(tier, 0) + chunks
@@ -160,7 +164,9 @@ class StorageTier(Protocol):
 
     def read_into(self, items: Sequence[Tuple[ChunkRef, memoryview]]) -> int:
         """Fill each destination view with its chunk's payload; returns
-        bytes read from this tier's medium."""
+        bytes read from this tier's medium.  A tier may also take
+        ``on_run``, a :data:`~repro.core.chunkstore.RunHook` it calls with
+        the chunks of each run as they land."""
         ...
 
 
@@ -285,9 +291,11 @@ class PackTier:
         *,
         parallel: bool = True,
         coalesce_gap: int = COALESCE_GAP,
+        on_run: Optional[RunHook] = None,
     ) -> int:
         return self.store.read_batch_into(
-            list(items), parallel=parallel, coalesce_gap=coalesce_gap
+            list(items), parallel=parallel, coalesce_gap=coalesce_gap,
+            on_run=on_run,
         )
 
 
@@ -759,6 +767,7 @@ class TieredChunkStore:
         parallel: bool = True,
         coalesce_gap: int = COALESCE_GAP,
         stats: Optional[TierReadStats] = None,
+        on_run: Optional[RunHook] = None,
     ) -> int:
         """Pack-tier read with retry/backoff.  ``KeyError`` (an index race
         with concurrent tier movement) passes through untouched — the
@@ -771,7 +780,8 @@ class TieredChunkStore:
         for attempt in range(policy.max_attempts):
             try:
                 n = self.pack.read_into(
-                    items, parallel=parallel, coalesce_gap=coalesce_gap
+                    items, parallel=parallel, coalesce_gap=coalesce_gap,
+                    on_run=on_run,
                 )
                 breaker.record_success()
                 return n
@@ -834,12 +844,15 @@ class TieredChunkStore:
         items: Sequence[Tuple[ChunkRef, memoryview]],
         *,
         stats: Optional[TierReadStats] = None,
+        on_run: Optional[RunHook] = None,
     ) -> int:
         """Remote-tier read: breaker-gated, retried, optionally hedged.
 
         Each attempt lands in scratch buffers and is copied into the caller
         views only on success, so an abandoned hedge (or a failed attempt)
-        can never partially fill a destination the restore will map."""
+        can never partially fill a destination the restore will map.
+        ``on_run`` sees each chunk right after its copy into the caller's
+        view."""
         remote = self.remote
         breaker = self.breakers["remote"]
         policy = self.retry
@@ -874,8 +887,10 @@ class TieredChunkStore:
                 time.sleep(delay)
             else:
                 breaker.record_success()
-                for (_, sv), (_, dv) in zip(scratch, items):
+                for (_, sv), (ref, dv) in zip(scratch, items):
                     dv[:] = sv
+                    if on_run is not None:
+                        on_run([(ref, dv)])
                 return n
         if time.monotonic() >= deadline:
             raise DeadlineExceededError(digests, "remote", last)
@@ -915,18 +930,23 @@ class TieredChunkStore:
     def _verify_views(
         self,
         checks: Sequence[Tuple[ChunkRef, memoryview, str]],
+        inline: Dict[str, str],
         *,
         stats: Optional[TierReadStats] = None,
     ) -> None:
-        """Digest-check freshly filled destination views; mismatches are
-        repaired in place (or raise :class:`ChunkIntegrityError`)."""
+        """Digest-check freshly filled destination views against the digests
+        their streams took (``inline``, digest -> what was read), hashing
+        here only the views no stream hashed; mismatches are repaired in
+        place (or raise :class:`ChunkIntegrityError`)."""
         if not checks:
             return
-        digests = digest_many([v for _, v, _ in checks])
+        rest = [v for r, v, _ in checks if r.digest not in inline]
+        got = iter(digest_many(rest) if rest else ())
         with self._stats_lock:
             self.verified_chunks += len(checks)
-        for (ref, view, tier), got in zip(checks, digests):
-            if got != ref.digest:
+        for ref, view, tier in checks:
+            digest = inline.get(ref.digest) or next(got)
+            if digest != ref.digest:
                 with self._stats_lock:
                     self.verify_failures += 1
                 if stats is not None:
@@ -1195,14 +1215,26 @@ class TieredChunkStore:
         promoted downward in the background (unless ``promote=False``).
         Returns bytes read across all tiers.
 
-        Stream-tier reads are retried and breaker-gated; once every stream
-        lands, each filled destination is digest-verified and corrupt or
-        unreadable chunks are healed from another tier or a registered
-        base source (:meth:`add_fallback_source`) — a restore either maps
-        byte-correct payloads or raises typed, never wrong bytes.
+        Stream-tier reads are retried and breaker-gated.  With
+        ``verify_reads`` each stream digests every chunk it fills right
+        after it lands (each pack run on the I/O thread that read it, each
+        RAM copy and remote chunk after its copy); once every stream has
+        landed, the digests are compared, chunks no stream hashed are hashed,
+        and corrupt or unreadable chunks are healed from another tier or a
+        registered base source (:meth:`add_fallback_source`) — a restore
+        either maps byte-correct payloads or raises typed, never wrong bytes.
         """
         if promote is None:
             promote = self.spec.promote_on_fetch
+        # digest -> the digest of what its stream landed; dict stores are
+        # atomic, and each primary digest is landed by one stream
+        inline: Dict[str, str] = {}
+
+        def hash_landed(landed: List[Tuple[ChunkRef, memoryview]]) -> None:
+            for ref, view in landed:
+                inline[ref.digest] = chunk_digest(view)
+
+        on_run = hash_landed if self.spec.verify_reads else None
         # the streams, up to verification; dup copies follow it
         with span("restore.read",
                   bytes=sum(r.size for r, _ in dests if not r.zero)):
@@ -1244,14 +1276,14 @@ class TieredChunkStore:
             local_fallback = False
             if remote_items:
                 remote_future = _get_fetch_pool().submit(
-                    self._remote_read, remote_items, stats=stats
+                    self._remote_read, remote_items, stats=stats, on_run=on_run
                 )
                 t_remote = time.perf_counter()
             if local_items:
                 try:
                     total += self._local_read(
                         local_items, parallel=parallel,
-                        coalesce_gap=coalesce_gap, stats=stats,
+                        coalesce_gap=coalesce_gap, stats=stats, on_run=on_run,
                     )
                 except KeyError:
                     # a concurrent demote() moved chunks between classification
@@ -1280,16 +1312,20 @@ class TieredChunkStore:
                 shards = [ram_items[i::nshards] for i in range(nshards)]
 
                 def _copy(shard):
-                    for _, view, payload in shard:
+                    for ref, view, payload in shard:
                         ctypes.memmove(
                             ctypes.addressof(ctypes.c_char.from_buffer(view)),
                             payload, len(payload),
                         )
+                        if on_run is not None:
+                            on_run([(ref, view)])
 
                 list(_get_io_pool().map(_copy, shards))
             else:
-                for _, view, payload in ram_items:
+                for ref, view, payload in ram_items:
                     view[:] = payload
+                    if on_run is not None:
+                        on_run([(ref, view)])
             total += ram_bytes
             promoting_bytes = 0
             remote_fallback = False
@@ -1338,7 +1374,7 @@ class TieredChunkStore:
                         _get_fetch_pool().submit(self._promote_payloads, pairs)
                     )
         if self.spec.verify_reads:
-            # verify once per primary destination, after every stream has
+            # check once per primary destination, after every stream has
             # landed and before dup copies fan the payloads out.  Fallback
             # re-dispatches verified (or healed) their own chunks already.
             checks: List[Tuple[ChunkRef, memoryview, str]] = [
@@ -1348,8 +1384,13 @@ class TieredChunkStore:
                 checks += [(r, v, "local") for r, v in local_items]
             if not remote_fallback:
                 checks += [(r, v, "remote") for r, v in remote_items]
-            with span("restore.verify", bytes=sum(len(v) for _, v, _ in checks)):
-                self._verify_views(checks, stats=stats)
+            hashed = {r.digest: r.size for r, _, _ in checks if r.digest in inline}
+            with span("restore.verify", bytes=sum(
+                    r.size for r, _, _ in checks if r.digest not in hashed)):
+                self._verify_views(checks, inline, stats=stats)
+            if stats is not None:
+                stats.inline_verified_bytes += sum(hashed.values()) + sum(
+                    len(v) for d, v in dup if d in hashed)
         for digest, view in dup:
             view[:] = primary[digest]
         if stats is not None:
