@@ -3,8 +3,7 @@ strategy, plus speed-up over `regular` and the optimal (warm) bound.
 
 Also emits machine-readable results (``--json BENCH_coldstart.json``):
 per-strategy A/B/D timings, restored bytes and eager-restore throughput
-(restored bytes / t_eager), a planned-vs-legacy restore-engine comparison
-for the snapshot strategies, per-function ``auto`` rows (the Eq. 1 planner
+(restored bytes / t_eager), per-function ``auto`` rows (the Eq. 1 planner
 picking the strategy at request time, compared against the best fixed
 strategy), warm-pool policy rows (LRU / GDSF / TTL warm-hit rates on a
 Zipf-skewed trace under a constrained budget), and a ``tiers`` section
@@ -34,7 +33,6 @@ from .common import (
     update_bench_json,
 )
 
-from repro.core import PLANNED_STRATEGIES
 from repro.core.tiers import TierSpec
 from repro.serving import (
     AdmissionConfig,
@@ -857,51 +855,9 @@ def run(
 
     for strategy in STRATEGIES:
         for spec in specs:
-            # snapshot strategies are pinned to the planned engine here so
-            # the engine comparison below can reuse these measurements
-            engine = "planned" if strategy in PLANNED_STRATEGIES else None
             table[spec.name][strategy] = _round_stats(
-                rounds(worker, spec, strategy, n=n_rounds, engine=engine)
+                rounds(worker, spec, strategy, n=n_rounds)
             )
-
-    # planned-vs-legacy eager-restore engine comparison (acceptance metric:
-    # restored bytes / t_eager must improve ≥2x for snapfaas and reap).
-    # Planned numbers come from the main table; only legacy is re-measured.
-    def _sum_stats(stats_per_spec) -> Dict[str, float]:
-        te = sum(s["t_eager_s"] for s in stats_per_spec)
-        tb = sum(s["boot_s"] for s in stats_per_spec)
-        nb = sum(s["eager_bytes"] for s in stats_per_spec)
-        return {
-            "t_eager_s": te,
-            "boot_s": tb,
-            "eager_bytes": nb,
-            "restored_GBps": (nb / te / 1e9) if te > 0 else 0.0,
-        }
-
-    engines: Dict[str, Dict[str, object]] = {}
-    for strategy in PLANNED_STRATEGIES:
-        agg: Dict[str, object] = {
-            "planned": _sum_stats([table[s.name][strategy] for s in specs]),
-            "legacy": _sum_stats([
-                _round_stats(rounds(worker, spec, strategy, n=n_rounds,
-                                    engine="legacy"))
-                for spec in specs
-            ]),
-        }
-        # null (not inf) when legacy restored nothing — keeps the JSON valid
-        agg["eager_speedup"] = (
-            agg["planned"]["restored_GBps"] / agg["legacy"]["restored_GBps"]
-            if agg["legacy"]["restored_GBps"] > 0 else None
-        )
-        engines[strategy] = agg
-        speedup = agg["eager_speedup"]
-        speedup_txt = f"{speedup:.2f}x" if speedup is not None else "n/a"
-        lines.append(csv_row(
-            f"fig5_engine.{strategy}", agg["planned"]["t_eager_s"] * 1e6,
-            f"planned_GBps={agg['planned']['restored_GBps']:.3f};"
-            f"legacy_GBps={agg['legacy']['restored_GBps']:.3f};"
-            f"speedup={speedup_txt}",
-        ))
 
     for spec in specs:
         base = table[spec.name]
@@ -1052,7 +1008,6 @@ def run(
         update_bench_json(json_path, "coldstart", {
             "config": {"n_functions": n_functions, "n_rounds": n_rounds},
             "per_function": {k: dict(v) for k, v in table.items()},
-            "engines": engines,
             "auto": auto,
             "policies": {
                 "config": {"budget_bytes": budget, "zipf_alpha": 1.1,

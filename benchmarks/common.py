@@ -191,8 +191,8 @@ def drop_file_cache(paths) -> None:
 
 
 def cold_request(worker: Worker, spec, strategy: str, *, drop_cache: bool = True,
-                 seed: int = 0, engine: str | None = None,
-                 clear_ram: bool = True, promote: bool | None = None):
+                 seed: int = 0, clear_ram: bool = True,
+                 promote: bool | None = None):
     """One measured cold request (page cache dropped first — packs AND the
     npz source artifacts, so every strategy's reads hit the medium).
 
@@ -209,21 +209,17 @@ def cold_request(worker: Worker, spec, strategy: str, *, drop_cache: bool = True
     return worker.invoke(InvocationRequest(
         function=spec.name, tokens=toks,
         options=ColdStartOptions(strategy=Strategy.coerce(strategy),
-                                 force_cold=True, engine=engine,
-                                 promote=promote),
+                                 force_cold=True, promote=promote),
     ))
 
 
-def rounds(worker: Worker, spec, strategy: str, n: int = 5, warmup: int = 1,
-           engine: str | None = None):
+def rounds(worker: Worker, spec, strategy: str, n: int = 5, warmup: int = 1):
     """n measured cold rounds (after jit warmup via a warm request)."""
     out = []
     for r in range(warmup):
-        cold_request(worker, spec, strategy, drop_cache=False, seed=r,
-                     engine=engine)
+        cold_request(worker, spec, strategy, drop_cache=False, seed=r)
     for r in range(n):
-        out.append(cold_request(worker, spec, strategy, seed=100 + r,
-                                engine=engine))
+        out.append(cold_request(worker, spec, strategy, seed=100 + r))
     return out
 
 

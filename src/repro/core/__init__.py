@@ -70,8 +70,6 @@ from .restore import (
     BasePool,
     MaterializedArray,
     RestoredInstance,
-    restore_layered,
-    restore_reap,
     restore_regular,
     restore_seuss,
 )
@@ -122,7 +120,7 @@ __all__ = [
     "build_working_set", "calibrate_container", "execute_restore_plan",
     "flatten_pytree", "lower_bound", "plan_restore", "predict",
     "predict_demand_paged", "resolve",
-    "restore_layered", "restore_reap", "restore_regular", "restore_seuss",
+    "restore_regular", "restore_seuss",
     "take_diff_snapshot", "take_snapshot", "unflatten_paths",
     "working_set_from_recording",
     "ZygoteRegistry",

@@ -670,49 +670,6 @@ class ChunkStore:
         except (KeyError, IOError, OSError):
             return False
 
-    def read_batch(
-        self, refs: Sequence[ChunkRef]
-    ) -> Dict[str, bytes]:
-        """Eager (readv-style) batched read.
-
-        Reads are grouped per pack and issued in offset order with adjacent
-        ranges coalesced — the `readv` of the paper's eager restoration.
-        Returns digest -> payload (zero chunks excluded; caller synthesizes).
-        """
-        by_pack: Dict[str, List[ChunkLoc]] = {}
-        wanted: Dict[Tuple[str, int], str] = {}
-        seen: Set[str] = set()
-        for ref in refs:
-            if ref.zero or ref.digest in seen:
-                continue
-            seen.add(ref.digest)
-            loc = self._loc_for(ref)
-            by_pack.setdefault(loc.pack, []).append(loc)
-            wanted[(loc.pack, loc.offset)] = ref.digest
-        out: Dict[str, bytes] = {}
-        for pack_id, locs in by_pack.items():
-            locs.sort(key=lambda l: l.offset)
-            path = os.path.join(self.root, "packs", f"{pack_id}.pack")
-            with open(path, "rb", buffering=0) as f:
-                # coalesce adjacent/overlapping ranges into sequential reads
-                i = 0
-                n = len(locs)
-                while i < n:
-                    start = locs[i].offset
-                    end = locs[i].offset + locs[i].size
-                    j = i + 1
-                    while j < n and locs[j].offset <= end + 64 * 1024:
-                        end = max(end, locs[j].offset + locs[j].size)
-                        j += 1
-                    f.seek(start)
-                    blob = f.read(end - start)
-                    for k in range(i, j):
-                        l = locs[k]
-                        d = wanted[(pack_id, l.offset)]
-                        out[d] = blob[l.offset - start : l.offset - start + l.size]
-                    i = j
-        return out
-
     # ------------------------------------------------------- zero-copy read
 
     def _pack_fd(self, pack_id: str) -> int:

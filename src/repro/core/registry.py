@@ -29,8 +29,6 @@ from .tiers import PrefetchStats, TieredChunkStore, TierSpec
 from .restore import (
     BasePool,
     RestoredInstance,
-    restore_layered,
-    restore_reap,
     restore_regular,
     restore_seuss,
 )
@@ -57,7 +55,7 @@ Path = str
 
 STRATEGIES = ("regular", "reap", "seuss", "snapfaas-", "snapfaas")
 
-# snapshot strategies served by the planned restore engine (the others
+# snapshot strategies, restored through a cached RestorePlan (the others
 # restore via source loaders and have no plan)
 PLANNED_STRATEGIES = ("reap", "snapfaas-", "snapfaas")
 
@@ -273,8 +271,7 @@ class ZygoteRegistry:
         dead = self.store.unpin(
             set(manifest_digests(rec.diff, rec.full)), owner=name
         )
-        freed = self.store.reclaim(dead) if hasattr(self.store, "reclaim") \
-            else self.store.forget(dead)
+        freed = self.store.reclaim(dead)
         for m in (rec.diff, rec.full):
             p = os.path.join(self.root, "manifests", f"{m.snapshot_id}.json")
             if os.path.exists(p):
@@ -305,8 +302,7 @@ class ZygoteRegistry:
         for rec in self.functions.values():
             referenced += rec.full.stored_bytes()
         unique = self.store.stored_bytes()
-        shared = self.store.shared_digests() \
-            if hasattr(self.store, "shared_digests") else set()
+        shared = self.store.shared_digests()
         return {
             "functions": len(self.functions),
             "referenced_bytes": referenced,
@@ -577,61 +573,37 @@ class ZygoteRegistry:
         residual_init: Optional[Callable[[Dict[str, Any]], Dict[str, Any]]] = None,
         source_loader: Optional[Callable[[], Dict[Path, np.ndarray]]] = None,
         base_loader: Optional[Callable[[], Dict[Path, np.ndarray]]] = None,
-        engine: Optional[str] = None,
         promote: Optional[bool] = None,
         demand_paged: bool = False,
     ) -> RestoredInstance:
         """Cold-start ``name`` with ``strategy``.
 
-        ``engine`` selects the snapshot-restore implementation for the
-        snapshot strategies: "planned" (default; cached RestorePlan +
-        zero-copy parallel scatter-reads) or "legacy" (the seed per-restore
-        resolve + 3-copy batched read — kept as the benchmark baseline).
-        Defaults to ``$REPRO_RESTORE_ENGINE`` or "planned".
+        The snapshot strategies (``PLANNED_STRATEGIES``) restore through
+        the function's cached :class:`RestorePlan` and its zero-copy
+        parallel scatter-reads; ``seuss`` and ``regular`` have no plan and
+        load from their source loaders.
 
         ``promote`` is the tier hint: whether remote-fetched eager chunks
         are promoted into the warm tiers (None → store default).
 
         ``demand_paged`` requests record-and-prefetch restore: background
-        prefetch of the recorded set plus lazy verified fault-in.  Honored
-        only for the planned snapshot strategies; everything else (legacy
-        engine, seuss/regular) silently restores eagerly — demand paging is
-        an optimisation, never a correctness dependency.
+        prefetch of the recorded set plus lazy verified fault-in.  The
+        loader strategies (seuss/regular) ignore it and restore eagerly —
+        demand paging is an optimisation, never a correctness dependency.
         """
         rec = self.functions[name]
-        base = self.bases[rec.runtime]
         pool = self.pools[rec.runtime]
-        engine = engine or os.environ.get("REPRO_RESTORE_ENGINE", "planned")
-        if engine not in ("planned", "legacy"):
-            raise ValueError(f"unknown restore engine {engine!r}")
-        if engine == "planned" and strategy in PLANNED_STRATEGIES:
+        if strategy in PLANNED_STRATEGIES:
             plan = self.restore_plan(name, strategy, demand_paged=demand_paged)
             return execute_restore_plan(
                 plan, self.store, pool if strategy != "reap" else None,
                 residual_init=residual_init, promote=promote,
                 arena=self.arena,
             )
-        if strategy == "snapfaas":
-            if rec.ws is None:
-                raise ValueError(f"{name}: no working set; run generate_working_set")
-            return restore_layered(
-                self.store, base, rec.diff, pool,
-                working_set=rec.ws, residual_init=residual_init, function=name,
-            )
-        if strategy == "snapfaas-":
-            return restore_layered(
-                self.store, base, rec.diff, pool,
-                working_set=None, residual_init=residual_init, function=name,
-            )
-        if strategy == "reap":
-            return restore_reap(
-                self.store, rec.full, working_set=rec.ws_full,
-                residual_init=residual_init, function=name,
-            )
         if strategy == "seuss":
             assert source_loader is not None, "seuss needs a source loader"
             return restore_seuss(
-                self.store, base, pool,
+                self.store, self.bases[rec.runtime], pool,
                 source_loader=source_loader, residual_init=residual_init,
                 function=name,
             )
@@ -658,8 +630,7 @@ class ZygoteRegistry:
         base = self.bases[rec.runtime]
         resolved = resolve(base, rec.diff)
         cats = self._category_refs(name)
-        shared_digests = self.store.shared_digests() \
-            if hasattr(self.store, "shared_digests") else set()
+        shared_digests = self.store.shared_digests()
 
         unique: Dict[str, int] = {}
         shared_hit_fracs: Dict[str, float] = {}

@@ -1,6 +1,8 @@
 """Snapshot restoration: the paper's §5.2 "Restoration", adapted.
 
-Four strategies are implemented, matching the paper's evaluation matrix:
+Five strategies, matching the paper's evaluation matrix; ``regular`` and
+``seuss`` are implemented here, the snapshot strategies in
+:mod:`.restore_plan` on this module's pool and array types:
 
 * ``regular``      — no snapshot: parse the variant's source checkpoint and
                      run full initialization (boot-from-kernel analogue).
@@ -27,15 +29,15 @@ Mechanical notes (documented deviations, see DESIGN.md §6):
 from __future__ import annotations
 
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
 from .chunkstore import ChunkRef, ChunkStore
 from .metrics import ColdStartMetrics, span
-from .snapshot import ArrayMeta, ResolvedArray, SnapshotManifest, resolve
-from .workingset import AccessLog, WorkingSet
+from .snapshot import ArrayMeta, SnapshotManifest
+from .workingset import AccessLog
 
 Path = str
 
@@ -376,164 +378,6 @@ class RestoredInstance:
 # ---------------------------------------------------------------------------
 # strategy implementations
 # ---------------------------------------------------------------------------
-
-def _assemble_private(
-    store: ChunkStore,
-    pool: Optional[BasePool],
-    path: Path,
-    ra: ResolvedArray,
-    eager_payloads: Dict[str, bytes],
-    eager_set: Optional[Set[Tuple[Path, int]]],
-) -> MaterializedArray:
-    """Build a private buffer: eager diff chunks are written now (from the
-    batched read); base chunks stay PENDING against the in-RAM pool (lazy
-    CoW-page materialization — page granularity, like the paper's mmap);
-    non-eager diff chunks stay pending against the store (demand faults)."""
-    meta = ra.meta
-    buf = np.zeros(meta.nbytes, dtype=np.uint8)
-    pending: List[Tuple[int, Optional[ChunkRef], str]] = []
-    for idx, (src, ref) in enumerate(ra.sources):
-        lo = idx * meta.chunk_bytes
-        hi = lo + ref.size
-        if src == "base":
-            if ref.zero:
-                continue
-            if pool is not None:
-                pending.append((idx, None, "pool"))  # lazy RAM memcpy
-            else:
-                # no pool (REAP): base chunks are part of the full snapshot
-                if eager_set is None or (path, idx) in eager_set:
-                    data = eager_payloads.get(ref.digest)
-                    if data is None:
-                        data = store.get_chunk(ref)
-                    buf[lo:hi] = np.frombuffer(data, dtype=np.uint8)
-                else:
-                    pending.append((idx, ref, "store"))
-        else:  # diff
-            if ref.zero:
-                continue
-            if eager_set is None or (path, idx) in eager_set:
-                data = eager_payloads.get(ref.digest)
-                if data is None:
-                    data = store.get_chunk(ref)
-                buf[lo:hi] = np.frombuffer(data, dtype=np.uint8)
-            else:
-                pending.append((idx, ref, "store"))
-    return MaterializedArray.private(path, meta, buf, pending, store, pool)
-
-
-def restore_layered(
-    store: ChunkStore,
-    base: SnapshotManifest,
-    diff: SnapshotManifest,
-    pool: BasePool,
-    *,
-    working_set: Optional[WorkingSet] = None,
-    residual_init: Optional[Callable[[Dict[str, Any]], Dict[str, Any]]] = None,
-    function: str = "",
-) -> RestoredInstance:
-    """SnapFaaS (working_set given) / SnapFaaS− (working_set None).
-
-    Steps map to Eq. 1:
-      A  pre-configuration + device-state restore
-      B  batched eager read of diff chunks (all, or WS only)
-      C  residual init
-      D  (charged later, during execution, by MaterializedArray)
-    """
-    strategy = "snapfaas" if working_set is not None else "snapfaas-"
-    m = ColdStartMetrics(strategy=strategy, function=function)
-
-    # A: resolve layering, restore device state, set up instance bookkeeping.
-    with span("restore.alloc") as s:
-        resolved = resolve(base, diff)
-        device_state = dict(base.device_state)
-        device_state.update(diff.device_state)
-    m.t_preconfig = s.seconds
-
-    # B: one batched (readv-style) eager read of the chosen diff chunks.
-    with span("restore.eager") as s:
-        eager_keys: List[Tuple[Path, int, ChunkRef]] = []
-        for path, ra in resolved.items():
-            for idx in ra.dirty_indices():
-                _, ref = ra.sources[idx]
-                if ref.zero:
-                    continue
-                if working_set is None or (path, idx) in working_set:
-                    eager_keys.append((path, idx, ref))
-        payloads = store.read_batch([r for _, _, r in eager_keys])
-        eager_set: Optional[Set[Tuple[Path, int]]] = (
-            {(p, i) for p, i, _ in eager_keys} if working_set is not None else None
-        )
-
-        arrays: Dict[Path, MaterializedArray] = {}
-        for path, ra in resolved.items():
-            if not ra.dirty_indices():
-                arrays[path] = MaterializedArray.shared(path, ra.meta, pool.get(path))
-                m.shared_bytes_mapped += ra.meta.nbytes
-            else:
-                arrays[path] = _assemble_private(store, pool, path, ra, payloads, eager_set)
-    m.t_eager = s.seconds
-    m.eager_bytes = sum(r.size for _, _, r in eager_keys)
-    m.eager_chunks = len(eager_keys)
-
-    # C: residual, un-memoizable initialization.
-    with span("restore.init") as s:
-        if residual_init is not None:
-            device_state = residual_init(device_state)
-    m.t_init = s.seconds
-
-    return RestoredInstance(
-        function=function, strategy=strategy, arrays=arrays,
-        device_state=device_state, metrics=m,
-    )
-
-
-def restore_reap(
-    store: ChunkStore,
-    full: SnapshotManifest,
-    *,
-    working_set: Optional[WorkingSet],
-    residual_init: Optional[Callable[[Dict[str, Any]], Dict[str, Any]]] = None,
-    function: str = "",
-) -> RestoredInstance:
-    """REAP_SF: full-function snapshot, WS eager + demand-page the rest.
-
-    Nothing is shared: every instance re-reads its entire state from disk
-    (eagerly or on fault) — the fundamental cost the paper's Fig. 6 shows.
-    """
-    m = ColdStartMetrics(strategy="reap", function=function)
-    with span("restore.alloc") as s:
-        resolved = resolve(None, full)  # every chunk reads as "diff" (unique)
-        device_state = dict(full.device_state)
-    m.t_preconfig = s.seconds
-
-    with span("restore.eager") as s:
-        eager_keys: List[Tuple[Path, int, ChunkRef]] = []
-        for path, ra in resolved.items():
-            for idx, (_, ref) in enumerate(ra.sources):
-                if ref.zero:
-                    continue
-                if working_set is None or (path, idx) in working_set:
-                    eager_keys.append((path, idx, ref))
-        payloads = store.read_batch([r for _, _, r in eager_keys])
-        eager_set = {(p, i) for p, i, _ in eager_keys}
-        arrays = {
-            path: _assemble_private(store, None, path, ra, payloads, eager_set)
-            for path, ra in resolved.items()
-        }
-    m.t_eager = s.seconds
-    m.eager_bytes = sum(r.size for _, _, r in eager_keys)
-    m.eager_chunks = len(eager_keys)
-
-    with span("restore.init") as s:
-        if residual_init is not None:
-            device_state = residual_init(device_state)
-    m.t_init = s.seconds
-    return RestoredInstance(
-        function=function, strategy="reap", arrays=arrays,
-        device_state=device_state, metrics=m,
-    )
-
 
 def restore_seuss(
     store: ChunkStore,

@@ -1,10 +1,7 @@
-"""Planned restore engine: resolve once, scatter-read forever.
+"""Restore engine of the snapshot strategies: resolve once, scatter-read
+on every cold start.
 
-The seed restore path re-resolved the (base, diff) layering, re-planned the
-eager read and re-walked every chunk's digest dict on *every* cold start —
-and each eager byte crossed three buffers on its way to the instance (pack
-read → digest-keyed bytes → frombuffer → destination slice).  This module
-splits restoration into:
+``reap``, ``snapfaas-`` and ``snapfaas`` restore in two steps:
 
 * :func:`build_restore_plan` — run once per (function, strategy) and cached
   on the :class:`~repro.core.registry.FunctionRecord`.  Resolves layering,

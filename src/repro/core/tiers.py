@@ -1158,46 +1158,6 @@ class TieredChunkStore:
             return bytes(buf), "remote"
         return None
 
-    def read_batch(self, refs: Sequence[ChunkRef]) -> Dict[str, bytes]:
-        """Legacy digest→payload batched read, tier-aware."""
-        out: Dict[str, bytes] = {}
-        local_refs: List[ChunkRef] = []
-        for ref in refs:
-            if ref.zero or ref.digest in out:
-                continue
-            payload = self.ram.get(ref.digest)
-            if payload is not None:
-                out[ref.digest] = payload
-            elif ref.digest in self.local:
-                local_refs.append(ref)
-            else:
-                out[ref.digest] = self.get_chunk(ref)  # remote (throttled)
-        if local_refs:
-            try:
-                fetched = self.local.read_batch(local_refs)
-            except KeyError:
-                # a concurrent demote moved chunks between classification
-                # and the read — re-fault each through the full hierarchy
-                for ref in local_refs:
-                    if ref.digest not in out:
-                        out[ref.digest] = self.get_chunk(ref)
-            else:
-                if self.spec.verify_reads and fetched:
-                    by_digest = {r.digest: r for r in local_refs}
-                    keys = list(fetched)
-                    digests = digest_many([fetched[k] for k in keys])
-                    for key, got in zip(keys, digests):
-                        if got != key:
-                            with self._stats_lock:
-                                self.verify_failures += 1
-                            ref = by_digest[key]
-                            buf = bytearray(ref.size)
-                            self._recover_chunk(ref, memoryview(buf),
-                                                "local", corrupt=True)
-                            fetched[key] = bytes(buf)
-                out.update(fetched)
-        return out
-
     def read_batch_into(
         self,
         dests: Sequence[Tuple[ChunkRef, memoryview]],

@@ -140,7 +140,6 @@ class ColdStartOptions:
 
     strategy: Strategy = Strategy.SNAPFAAS
     force_cold: bool = False            # bypass the warm pool (bench/measure)
-    engine: Optional[str] = None        # "planned" | "legacy" | None (env default)
     prefetch: bool = False              # promote the WS to warm tiers first
     #: which eager set the prefetch hint warms: "ws" (default), "diff",
     #: "ws_full" or "full".  The full-snapshot categories warm the shared

@@ -328,7 +328,7 @@ class Worker:
     ) -> Optional[jax.Array]:
         """Apply this array's diff chunks to the device-resident base copy.
 
-        The planned restore engine leaves patchable arrays as (packed diff
+        The restore engine leaves patchable arrays as (packed diff
         rows + selection map) instead of assembling them on the host; here
         the ``snapshot_patch`` kernel fuses base ⊕ diff directly in device
         memory — base chunks never cross the host, diff chunks cross it once
@@ -466,7 +466,6 @@ class Worker:
                 inst = self.registry.cold_start(
                     fn, strategy.value,
                     residual_init=lambda ds: {**ds, "kv_ready": True},
-                    engine=opts.engine,
                     promote=opts.promote,
                     demand_paged=demand_paged,
                     **loaders,

@@ -90,8 +90,6 @@ class TestDigestCollision:
             store.get_chunk(bad)
         with pytest.raises(DigestCollisionError):
             store.read_batch_into([(bad, memoryview(bytearray(bad.size)))])
-        with pytest.raises(DigestCollisionError):
-            store.read_batch([bad])
 
     def test_index_load_rejects_colliding_lengths(self, tmp_path):
         """Regression: a (v0) index aliasing one digest to two different

@@ -212,11 +212,9 @@ def test_every_stream_hashes_what_it_lands(tmp_path, where, post_pass):
 # ------------------------------------------ callers that verify nothing hash nothing
 
 def test_flat_store_restores_hash_nothing(tmp_path, digests_taken):
-    """The flat store's restore (the planned engine, the base pool load and
-    the legacy layered restore over a plain ``ChunkStore``) computes no
-    digest: only the tiered store verifies."""
-    from repro.core.restore import restore_layered
-
+    """The flat store's restore (the restore engine and the base pool load
+    over a plain ``ChunkStore``) computes no digest: only the tiered store
+    verifies."""
     reg = ZygoteRegistry(str(tmp_path / "reg"), chunk_bytes=CHUNK)
     rng = np.random.default_rng(4)
     base = {f"l{i}": {"w": rng.standard_normal((64, 32)).astype(np.float32)}
@@ -230,11 +228,9 @@ def test_flat_store_restores_hash_nothing(tmp_path, digests_taken):
     digests_taken.clear()
     pool = BasePool.load(flat, reg.bases["fam"])
     planned = execute_restore_plan(plan, flat, pool)
-    legacy = restore_layered(flat, reg.bases["fam"], reg.functions["fn"].diff, pool)
     assert digests_taken == []
     for p, a in flatten_pytree(variant).items():
         np.testing.assert_array_equal(planned.value(p), a, err_msg=p)
-        np.testing.assert_array_equal(legacy.value(p), a, err_msg=p)
 
 
 def test_tiered_store_without_verify_reads_hashes_nothing(tmp_path, digests_taken):

@@ -166,29 +166,37 @@ class TestTierLookupReadRaces:
         assert fired and store.remote.has(victim.digest)
 
     def test_read_batch_survives_racing_demote(self, tmp_path):
-        """Same window for the legacy batched read: the local sub-batch
-        re-faults through the hierarchy when a demote races it."""
+        """Same window for the scatter-read: a demote landing between
+        classification and the pack read makes the read raise KeyError, and
+        the local sub-batch re-dispatches through the hierarchy."""
         store = TieredChunkStore(
             str(tmp_path / "s"), spec=TierSpec(ram_bytes=0, **FAST_REMOTE)
         )
         payloads = _payloads(np.random.default_rng(1), 4)
         refs = _fill(store, payloads)
-        orig = store.local.read_batch
-        fired = {}
+        orig = store.pack.read_into
+        calls = []
 
-        def racing(batch):
-            if not fired:
-                fired["x"] = True
-                store.demote([refs[1]])
-            return orig(batch)
+        def racing(items, **kw):
+            calls.append(len(items))
+            if len(calls) == 1:
+                store.demote([refs[1]])   # classified local, now remote
+            return orig(items, **kw)
 
-        store.local.read_batch = racing
+        store.pack.read_into = racing
+        bufs = [bytearray(r.size) for r in refs]
+        stats = TierReadStats()
         try:
-            out = store.read_batch(refs)
+            store.read_batch_into(
+                [(r, memoryview(b)) for r, b in zip(refs, bufs)], stats=stats
+            )
         finally:
-            store.local.read_batch = orig
-        for ref, payload in zip(refs, payloads):
-            assert out[ref.digest] == payload
+            store.pack.read_into = orig
+        # the first pack read missed the demoted chunk; the fallback read
+        # the rest locally and the demoted chunk from the remote tier
+        assert calls == [4, 3]
+        assert stats.tier_bytes == {"local": 3 * 6000, "remote": 6000}
+        assert bufs == [bytearray(p) for p in payloads]
 
     def test_scatter_reads_byte_identical_under_movement_storm(self, tmp_path):
         """Sustained concurrent movement (demote/prefetch cycles) against

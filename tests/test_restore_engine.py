@@ -1,5 +1,6 @@
-"""Planned restore engine: equivalence with the seed path, scatter-read
-correctness, coalescing properties, and the on-device patch path."""
+"""Restore engine: restored bytes equal the variant wherever its chunks
+live, scatter-read correctness, coalescing properties, and the on-device
+patch path."""
 
 import gc
 
@@ -12,6 +13,7 @@ from repro.core import (
     ChunkStore,
     ZygoteRegistry,
     flatten_pytree,
+    resolve,
 )
 from repro.core.chunkstore import COALESCE_GAP, coalesce_ranges, scan_chunks
 
@@ -48,26 +50,48 @@ def _registry(tmp_path, *, ws=True):
 
 # ---------------------------------------------------------- engine equivalence
 
-class TestEngineEquivalence:
-    @pytest.mark.parametrize("strategy", ["snapfaas", "snapfaas-", "reap"])
-    def test_planned_matches_legacy_bytes(self, tmp_path, strategy):
-        """Restored bytes from the plan-based path are byte-identical to the
-        seed (legacy) path, for every array and every snapshot strategy."""
-        reg, variant = _registry(tmp_path)
-        legacy = reg.cold_start("fn", strategy, engine="legacy")
-        planned = reg.cold_start("fn", strategy, engine="planned")
-        assert set(legacy.arrays) == set(planned.arrays)
-        for path in legacy.arrays:
-            a, b = legacy.value(path), planned.value(path)
-            assert a.dtype == b.dtype and a.shape == b.shape, path
-            np.testing.assert_array_equal(a, b, err_msg=f"{strategy}/{path}")
+# the eager set each snapshot strategy reads, as a prefetch category
+EAGER_CATEGORY = {"snapfaas": "ws", "snapfaas-": "diff", "reap": "ws_full"}
 
+
+def _expected_eager(reg, strategy):
+    """(bytes, chunks) of the non-zero dirty working-set chunks, summed
+    from the manifests: what a restore of ``strategy`` reads eagerly."""
+    rec = reg.functions["fn"]
+    if strategy == "reap":   # every chunk of the full snapshot reads as dirty
+        resolved, ws = resolve(None, rec.full), rec.ws_full
+    else:
+        resolved = resolve(reg.bases["fam"], rec.diff)
+        ws = rec.ws if strategy == "snapfaas" else None
+    refs = [
+        ra.sources[i][1] for path, ra in resolved.items()
+        for i in ra.dirty_indices()
+        if not ra.sources[i][1].zero and (ws is None or (path, i) in ws)
+    ]
+    return sum(r.size for r in refs), len(refs)
+
+
+class TestEngineEquivalence:
+    @pytest.mark.parametrize("tier", ["ram", "local", "remote"])
     @pytest.mark.parametrize("strategy", ["snapfaas", "snapfaas-", "reap"])
-    def test_planned_matches_source_variant(self, tmp_path, strategy):
+    def test_planned_matches_source_variant(self, tmp_path, strategy, tier):
+        """Restored bytes equal the variant whichever tier holds the eager
+        set: the RAM cache (prefetched), the local packs only, or the
+        remote tier (the function's own chunks demoted)."""
         reg, variant = _registry(tmp_path)
-        inst = reg.cold_start("fn", strategy, engine="planned")
+        reg.store.drop_page_cache(clear_ram=True)
+        if tier == "ram":
+            reg.prefetch_working_set("fn", EAGER_CATEGORY[strategy])
+        elif tier == "remote":
+            assert reg.demote_function("fn") > 0
+        inst = reg.cold_start("fn", strategy)
         for path, expected in flatten_pytree(variant).items():
             np.testing.assert_array_equal(inst.value(path), expected, err_msg=path)
+        m = inst.metrics
+        assert m.tier_bytes.get(tier, 0) > 0, m.tier_bytes
+        assert sum(m.tier_bytes.values()) == m.eager_bytes
+        if tier != "remote":   # reap's base chunks stay local when demoted
+            assert set(m.tier_bytes) == {tier}, m.tier_bytes
 
     def test_seuss_and_regular_match(self, tmp_path):
         """The loader strategies restore the same values (they bypass the
@@ -100,16 +124,16 @@ class TestEngineEquivalence:
     def test_eager_accounting_matches_legacy(self, tmp_path):
         reg, _ = _registry(tmp_path)
         for strategy in ("snapfaas", "snapfaas-", "reap"):
-            a = reg.cold_start("fn", strategy, engine="legacy").metrics
-            b = reg.cold_start("fn", strategy, engine="planned").metrics
-            assert a.eager_bytes == b.eager_bytes, strategy
-            assert a.eager_chunks == b.eager_chunks, strategy
+            m = reg.cold_start("fn", strategy).metrics
+            want = _expected_eager(reg, strategy)
+            assert want[1] > 0, strategy
+            assert (m.eager_bytes, m.eager_chunks) == want, strategy
 
     def test_demand_paging_still_works(self, tmp_path):
         """With an empty WS nothing is eager; first read demand-faults."""
         reg, variant = _registry(tmp_path)
         reg.generate_working_set("fn", AccessLog())
-        inst = reg.cold_start("fn", "snapfaas", engine="planned")
+        inst = reg.cold_start("fn", "snapfaas")
         assert inst.metrics.eager_bytes == 0
         np.testing.assert_array_equal(
             inst.value("layer2/w"), variant["layer2"]["w"]
@@ -167,17 +191,6 @@ class TestReadBatchInto:
             )
             out[parallel] = [bytes(b) for b in bufs]
         assert out[False] == out[True]
-
-    def test_read_batch_dedupes_repeats(self, tmp_path):
-        """The same digest requested N times is planned once (seed appended
-        it to by_pack N times) and still returned correctly."""
-        store, refs, payloads = self._store(tmp_path, n=8)
-        batch = store.read_batch(list(refs) * 5)
-        for r, p in zip(refs, payloads):
-            if r.zero:
-                assert r.digest not in batch
-            else:
-                assert batch[r.digest] == p
 
     def test_scan_chunks_matches_per_chunk(self, tmp_path):
         rng = np.random.default_rng(3)
@@ -410,7 +423,7 @@ class TestDevicePatch:
         host-assembled array — validates the layout fed to the Pallas
         snapshot_patch kernel."""
         reg, variant = _registry(tmp_path)
-        inst = reg.cold_start("fn", "snapfaas", engine="planned")
+        inst = reg.cold_start("fn", "snapfaas")
         ma = inst.arrays["layer2/w"]
         assert ma.patch is not None
         meta = ma.meta
@@ -436,7 +449,7 @@ class TestDevicePatch:
         from repro.kernels.snapshot_patch import patch_apply_op
 
         reg, variant = _registry(tmp_path)
-        inst = reg.cold_start("fn", "snapfaas", engine="planned")
+        inst = reg.cold_start("fn", "snapfaas")
         ma = inst.arrays["layer2/w"]
         meta = ma.meta
         itemsize = np.dtype(meta.dtype).itemsize
@@ -456,9 +469,11 @@ class TestDevicePatch:
 
     def test_worker_serves_patched_params(self, tmp_path):
         """Worker request path picks the device-patch branch and produces
-        the same logits as a host-assembled instance."""
+        the logits of a never-snapshotted instance: the family's jitted
+        forward on the variant tree."""
         jax = pytest.importorskip("jax")
-        from repro.models import build_model
+        from repro.core import unflatten_paths
+        from repro.models import Batch, build_model
         from repro.models.config import ModelConfig
         from repro.serving.trace import request_tokens
         from repro.serving.worker import FunctionSpec, Worker
@@ -484,17 +499,13 @@ class TestDevicePatch:
         toks = request_tokens(spec, np.random.default_rng(0), cfg.vocab_size,
                               seq=8)
 
-        def cold(engine=None):
-            return worker.invoke(InvocationRequest(
-                function="fn", tokens=toks,
-                options=ColdStartOptions(strategy=Strategy.SNAPFAAS,
-                                         force_cold=True, engine=engine),
-            ))
-
-        r_planned = cold()
+        res = worker.invoke(InvocationRequest(
+            function="fn", tokens=toks,
+            options=ColdStartOptions(strategy=Strategy.SNAPFAAS, force_cold=True),
+        ))
         inst = worker.pool.get("fn")
         assert any(a._dev is not None for a in inst.arrays.values()), \
             "device patch path did not fire"
-        r_legacy = cold(engine="legacy")
-        np.testing.assert_allclose(r_planned.output, r_legacy.output,
-                                   rtol=1e-5, atol=1e-6)
+        fwd = jax.jit(lambda p, t: model.logits(p, Batch(tokens=t)))
+        want = np.asarray(fwd(unflatten_paths(variant), toks))[:, -1, :8]
+        np.testing.assert_allclose(res.output, want, rtol=1e-5, atol=1e-6)

@@ -168,14 +168,17 @@ class TestTieredEquivalence:
         )
         refs = _fill(store, payloads)
         store.demote(refs[4:])
+        store.prefetch(refs[2:3])   # refs[:2] are zero chunks
+        bufs = [bytearray(r.size) for r in refs]
+        stats = TierReadStats()
+        store.read_batch_into([(r, memoryview(b)) for r, b in zip(refs, bufs)],
+                              stats=stats, promote=False)
+        assert bufs == [bytearray(p) for p in payloads]
+        assert set(stats.tier_bytes) == {"ram", "local", "remote"}
+        assert sum(stats.tier_bytes.values()) == sum(
+            r.size for r in refs if not r.zero)
         for r, p in zip(refs, payloads):
             assert store.get_chunk(r) == p
-        batch = store.read_batch(refs)
-        for r, p in zip(refs, payloads):
-            if r.zero:
-                assert r.digest not in batch
-            else:
-                assert batch[r.digest] == p
 
 
 # ----------------------------------------------------- promotion / demotion
