@@ -114,6 +114,12 @@ class Worker:
         self.models: Dict[str, Model] = {}
         self.specs: Dict[str, FunctionSpec] = {}
         self._fwd: Dict[str, callable] = {}
+        # per-family parameter template (a tree of ShapeDtypeStructs), built
+        # at registration: a request reads only its paths, and tracing the
+        # family's init takes ~0.3 s of host time on mamba2-780m's 48 layers.
+        # ``template_builds`` counts the builds per family.
+        self._templates: Dict[str, PyTree] = {}
+        self.template_builds: Dict[str, int] = {}
         # device-ready base pools / on-disk base images, per family.  Eagerly
         # initialised: the former getattr-lazy init raced register_function
         # against register_runtime (latent AttributeError).
@@ -139,6 +145,8 @@ class Worker:
             fwd = jax.jit(
                 lambda p, tokens: model.logits(p, Batch(tokens=tokens)))
         self._fwd[family] = fwd
+        self._templates[family] = model.param_shapes()
+        self.template_builds[family] = self.template_builds.get(family, 0) + 1
         # device-ready view of the base pool: shared (CoW-clean) leaves are
         # served zero-copy to every instance of the family — the runtime
         # analogue of the paper's mmap'd in-RAM base snapshot.
@@ -388,7 +396,7 @@ class Worker:
         row-granular, full otherwise.  Host-path touches are logged by the
         MaterializedArrays themselves (``attach_access_log``)."""
         with span("exec.shapes"):
-            template = self.models[spec.family].param_shapes()
+            template = self._templates[spec.family]
         rows = dict(spec.touched_rows)
         for k, v in (request_rows or {}).items():
             rows[k] = np.union1d(np.asarray(rows.get(k, []), np.int64), v)
